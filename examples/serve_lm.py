@@ -57,6 +57,8 @@ def main():
 
     mesh = None
     if args.plan_mesh:
+        # before any JAX device use: plan_serving may fork simulator
+        # workers, and a process whose backend is up holds the chip
         mesh_axes, report = plan_serving(
             arch, hardware=args.hardware, batch=args.batch,
             context_len=args.prompt_len + args.new_tokens)
@@ -74,9 +76,11 @@ def main():
     out = greedy_generate(arch, params, prompts, args.new_tokens, cfg, mesh=mesh)
     dt = time.time() - t0
     total_new = args.batch * args.new_tokens
-    where = f"{len(jax.devices())} devices" if mesh is not None else "CPU"
+    dev = jax.devices()[0]
+    used = len(jax.devices()) if mesh is not None else 1
     print(f"{arch.name}: generated {out.shape} in {dt:.2f}s "
-          f"({total_new / dt:.1f} tok/s on {where}, batch={args.batch})")
+          f"({total_new / dt:.1f} tok/s on {used} x {dev.platform} "
+          f"{dev.device_kind}, batch={args.batch})")
     print("first sequence:", out[0][:16].tolist())
 
 

@@ -17,14 +17,10 @@ Per cell this produces (artifacts/dryrun/<cell>.json):
 * MODEL_FLOPS (6·N_active·D for training) for the useful-compute ratio.
 """
 
-# MUST precede any jax import (jax locks device count on first init).
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import argparse
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import time
@@ -344,6 +340,12 @@ def main(argv=None):
     ap.add_argument("--palm-hardware", type=str, default="tpu_v5e_4x4",
                     help="hardware preset the --palm-trace simulation runs on")
     args = ap.parse_args(argv)
+
+    # 512 host devices on the CPU backend; must precede its first use,
+    # which locks the device count (the --all children inherit both)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
     out_dir = Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)

@@ -9,26 +9,22 @@ from __future__ import annotations
 from typing import Mapping
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_serving_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_serving_mesh"]
 
 
-def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older JAX meshes are
-    # implicitly Auto, so just drop the kwarg there
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagates the
+    shardings the planner pins)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod adds a leading 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_serving_mesh(mesh_axes: Mapping[str, int]):
@@ -44,4 +40,4 @@ def make_serving_mesh(mesh_axes: Mapping[str, int]):
     ``--xla_force_host_platform_device_count`` for CPU dry-runs).
     """
     shape = (int(mesh_axes["data"]), int(mesh_axes["model"]))
-    return _make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))

@@ -30,6 +30,7 @@ from ..train.data import DataCfg, PrefetchIterator, SyntheticDataset
 from ..train.fault_tolerance import StragglerMonitor
 from ..train.optim import OptimizerCfg
 from ..train.step import TrainCfg, init_train_state, make_train_step
+from .compile_cache import enable_compile_cache
 
 __all__ = ["scale_arch", "train_loop", "main"]
 
@@ -114,6 +115,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     arch = scale_arch(get_config(args.arch), args.scale)
     cfg = TrainCfg(
         run=RunCfg(q_chunk=0, remat=False),

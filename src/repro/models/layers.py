@@ -259,7 +259,6 @@ def moe_ep(
     partial outputs. Experts are zero-padded to a multiple of the axis
     size (e.g. granite-moe's 40 -> 48 on a 16-way axis).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     H = x.shape[-1]
@@ -319,11 +318,11 @@ def moe_ep(
 
     t_spec = P(data_axes, None)
     e_spec = P(expert_axis, None, None)
-    out, load, drop = shard_map(
+    out, load, drop = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(t_spec, P(None, None), e_spec, e_spec, e_spec),
         out_specs=(t_spec, P(None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, router, wg, wi, wo)
     aux = {"load": load.astype(jnp.float32),
            "drop_fraction": drop,

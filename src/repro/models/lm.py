@@ -74,7 +74,9 @@ def _residual_spec(cfg: "RunCfg") -> Tuple:
 # ---------------------------------------------------------------------------
 
 def _dense(key, shape, dtype, scale=None):
-    fan_in = shape[0] if len(shape) >= 2 else shape[-1]
+    # fan-in is the input dim of the matrix; leading dims are the layer
+    # stack ([L, H, F]) and experts ([L, E, H, F])
+    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     scale = (1.0 / fan_in) ** 0.5 if scale is None else scale
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 

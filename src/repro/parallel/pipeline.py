@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_apply", "make_pipeline_loss"]
 
@@ -69,8 +68,8 @@ def pipeline_apply(
         # microbatch g exits the last stage at tick g + S - 1
         return ys[S - 1:]
 
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                       check_vma=False)
     return fn(stage_params, microbatches)
 
 

@@ -20,20 +20,10 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-
-    # jax.sharding.AxisType landed after 0.4.x; older JAX meshes are
-    # implicitly Auto, so just drop the kwarg there.
-    try:
-        from jax.sharding import AxisType
-        def make_mesh(shape, names):
-            return jax.make_mesh(shape, names,
-                                 axis_types=(AxisType.Auto,) * len(names))
-    except ImportError:
-        def make_mesh(shape, names):
-            return jax.make_mesh(shape, names)
+    from jax import shard_map
 
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import scale_arch
     from repro.models import RunCfg, init_params
     from repro.train.optim import init_opt_state
