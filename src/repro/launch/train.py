@@ -78,11 +78,17 @@ def train_loop(arch: ArchConfig, cfg: TrainCfg, data_cfg: DataCfg, steps: int,
     losses = []
     try:
         for step in range(start_step, steps):
-            batch = next(it)
-            t0 = time.time()
-            params, opt_state, metrics = train_step(params, opt_state, batch)
-            loss = float(metrics["loss"])
-            dt = time.time() - t0
+            # the chip benchmark's span names, so a trace of this loop
+            # splits device idle time the way the benchmark's does
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with jax.profiler.TraceAnnotation("data_wait"):
+                    batch = next(it)
+                t0 = time.time()
+                with jax.profiler.TraceAnnotation("dispatch"):
+                    params, opt_state, metrics = train_step(params, opt_state, batch)
+                with jax.profiler.TraceAnnotation("loss_fetch"):
+                    loss = float(metrics["loss"])
+                dt = time.time() - t0
             losses.append(loss)
             ev = monitor.record(step, dt)
             if ev:

@@ -102,57 +102,58 @@ def attention(
     ``q_chunk > 0`` scans over query chunks (O(S * chunk) memory);
     ``window > 0`` additionally slices KV to the live span per chunk.
     """
-    B, S, nh, hd = q.shape
-    nkv = k.shape[2]
-    g = nh // nkv
-    qg = q.reshape(B, S, nkv, g, hd)
+    with jax.named_scope("attention"):
+        B, S, nh, hd = q.shape
+        nkv = k.shape[2]
+        g = nh // nkv
+        qg = q.reshape(B, S, nkv, g, hd)
 
-    def mask_for(q_pos: jax.Array, k_pos: jax.Array) -> jax.Array:
-        m = jnp.ones((q_pos.shape[0], k_pos.shape[0]), dtype=bool)
-        if causal:
-            m &= q_pos[:, None] >= k_pos[None, :]
+        def mask_for(q_pos: jax.Array, k_pos: jax.Array) -> jax.Array:
+            m = jnp.ones((q_pos.shape[0], k_pos.shape[0]), dtype=bool)
+            if causal:
+                m &= q_pos[:, None] >= k_pos[None, :]
+            if window:
+                m &= k_pos[None, :] > q_pos[:, None] - window
+            return m
+
+        if not q_chunk or S <= q_chunk:
+            pos = jnp.arange(S)
+            out = _attend(qg, k, v, mask_for(pos, pos))
+            return out.reshape(B, S, nh, hd)
+
+        assert S % q_chunk == 0, (S, q_chunk)
+        n_chunks = S // q_chunk
+        qc = qg.reshape(B, n_chunks, q_chunk, nkv, g, hd)
+
         if window:
-            m &= k_pos[None, :] > q_pos[:, None] - window
-        return m
+            span = min(S, window + q_chunk)  # static KV slice per chunk
 
-    if not q_chunk or S <= q_chunk:
-        pos = jnp.arange(S)
-        out = _attend(qg, k, v, mask_for(pos, pos))
-        return out.reshape(B, S, nh, hd)
+            def chunk_fn(_, inputs):
+                idx, qi = inputs
+                q0 = idx * q_chunk
+                k0 = jnp.maximum(q0 + q_chunk - span, 0)
+                ks = lax.dynamic_slice_in_dim(k, k0, span, axis=1)
+                vs = lax.dynamic_slice_in_dim(v, k0, span, axis=1)
+                # dynamic positions -> build mask from absolute indices
+                q_pos = q0 + jnp.arange(q_chunk)
+                k_pos = k0 + jnp.arange(span)
+                m = q_pos[:, None] >= k_pos[None, :]
+                m &= k_pos[None, :] > q_pos[:, None] - window
+                return None, _attend(qi, ks, vs, m)
+        else:
+            def chunk_fn(_, inputs):
+                idx, qi = inputs
+                q0 = idx * q_chunk
+                q_pos = q0 + jnp.arange(q_chunk)
+                k_pos = jnp.arange(S)
+                m = q_pos[:, None] >= k_pos[None, :] if causal else \
+                    jnp.ones((q_chunk, S), dtype=bool)
+                return None, _attend(qi, k, v, m)
 
-    assert S % q_chunk == 0, (S, q_chunk)
-    n_chunks = S // q_chunk
-    qc = qg.reshape(B, n_chunks, q_chunk, nkv, g, hd)
-
-    if window:
-        span = min(S, window + q_chunk)  # static KV slice per chunk
-
-        def chunk_fn(_, inputs):
-            idx, qi = inputs
-            q0 = idx * q_chunk
-            k0 = jnp.maximum(q0 + q_chunk - span, 0)
-            ks = lax.dynamic_slice_in_dim(k, k0, span, axis=1)
-            vs = lax.dynamic_slice_in_dim(v, k0, span, axis=1)
-            # dynamic positions -> build mask from absolute indices
-            q_pos = q0 + jnp.arange(q_chunk)
-            k_pos = k0 + jnp.arange(span)
-            m = q_pos[:, None] >= k_pos[None, :]
-            m &= k_pos[None, :] > q_pos[:, None] - window
-            return None, _attend(qi, ks, vs, m)
-    else:
-        def chunk_fn(_, inputs):
-            idx, qi = inputs
-            q0 = idx * q_chunk
-            q_pos = q0 + jnp.arange(q_chunk)
-            k_pos = jnp.arange(S)
-            m = q_pos[:, None] >= k_pos[None, :] if causal else \
-                jnp.ones((q_chunk, S), dtype=bool)
-            return None, _attend(qi, k, v, m)
-
-    idxs = jnp.arange(n_chunks)
-    _, out = lax.scan(chunk_fn, None, (idxs, jnp.moveaxis(qc, 1, 0)))
-    out = jnp.moveaxis(out, 0, 1).reshape(B, S, nh, hd)
-    return out
+        idxs = jnp.arange(n_chunks)
+        _, out = lax.scan(chunk_fn, None, (idxs, jnp.moveaxis(qc, 1, 0)))
+        out = jnp.moveaxis(out, 0, 1).reshape(B, S, nh, hd)
+        return out
 
 
 def decode_attention(
@@ -166,9 +167,10 @@ def decode_attention(
     g = nh // nkv
     qg = q.reshape(B, Sq, nkv, g, hd)
     S = k_cache.shape[1]
-    valid = jnp.arange(S)[None, :] < cache_len  # [1, S]
-    out = _attend(qg, k_cache, v_cache, jnp.broadcast_to(valid, (Sq, S)))
-    return out.reshape(B, Sq, nh, hd)
+    with jax.named_scope("attention"):
+        valid = jnp.arange(S)[None, :] < cache_len  # [1, S]
+        out = _attend(qg, k_cache, v_cache, jnp.broadcast_to(valid, (Sq, S)))
+        return out.reshape(B, Sq, nh, hd)
 
 
 # ---------------------------------------------------------------------------

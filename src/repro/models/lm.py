@@ -173,36 +173,38 @@ def param_count(params) -> int:
 def _run_attn(arch: ArchConfig, p, h: jax.Array, positions: jax.Array, cfg: RunCfg):
     B, S, H = h.shape
     nh, nkv, hd = arch.n_heads, arch.n_kv, arch.head_dim
-    # Megatron-SP pattern: gather sequence, shard heads over "model" —
-    # explicit hints so GSPMD never falls back to gathering whole weights
-    h = _cst(h, cfg, (cfg.batch_axes, None, None))
-    q = _cst(h @ p["wq"], cfg, (cfg.batch_axes, None, "model")).reshape(B, S, nh, hd)
-    k = _cst(h @ p["wk"], cfg, (cfg.batch_axes, None, "model")).reshape(B, S, nkv, hd)
-    v = _cst(h @ p["wv"], cfg, (cfg.batch_axes, None, "model")).reshape(B, S, nkv, hd)
-    q, k = rope(q, positions), rope(k, positions)
-    o = attention(q, k, v, causal=arch.causal, window=arch.window, q_chunk=cfg.q_chunk)
-    return o.reshape(B, S, nh * hd) @ p["wo"]
+    with jax.named_scope("attn_proj"):
+        # Megatron-SP pattern: gather sequence, shard heads over "model" —
+        # explicit hints so GSPMD never falls back to gathering whole weights
+        h = _cst(h, cfg, (cfg.batch_axes, None, None))
+        q = _cst(h @ p["wq"], cfg, (cfg.batch_axes, None, "model")).reshape(B, S, nh, hd)
+        k = _cst(h @ p["wk"], cfg, (cfg.batch_axes, None, "model")).reshape(B, S, nkv, hd)
+        v = _cst(h @ p["wv"], cfg, (cfg.batch_axes, None, "model")).reshape(B, S, nkv, hd)
+        q, k = rope(q, positions), rope(k, positions)
+        o = attention(q, k, v, causal=arch.causal, window=arch.window, q_chunk=cfg.q_chunk)
+        return o.reshape(B, S, nh * hd) @ p["wo"]
 
 
 def _run_ssm(arch: ArchConfig, p, h: jax.Array, cfg: RunCfg):
-    B, S, H = h.shape
-    di, N, nh = arch.d_inner, arch.ssm_state, arch.ssm_n_heads
-    hp = arch.ssm_headdim
-    proj = h @ p["in_proj"]
-    z, xbc, dtr = jnp.split(proj, [di, 2 * di + 2 * N], axis=-1)
-    # causal depthwise conv over (x, B, C)
-    K = arch.conv_width
-    padded = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
-    conv = sum(padded[:, k:k + S] * p["conv_w"][k] for k in range(K)) + p["conv_b"]
-    xbc = silu(conv).astype(h.dtype)
-    xs, Bm, Cm = jnp.split(xbc, [di, di + N], axis=-1)
-    dt = jax.nn.softplus(dtr.astype(jnp.float32) + p["dt_bias"])
-    A = -jnp.exp(p["A_log"])
-    y = ssd_scan(xs.reshape(B, S, nh, hp), dt, A, Bm, Cm, chunk=cfg.ssd_chunk)
-    y = y + p["D"].astype(y.dtype)[None, None, :, None] * xs.reshape(B, S, nh, hp)
-    y = y.reshape(B, S, di)
-    y = rmsnorm(y * silu(z), p["ssm_norm"])
-    return y @ p["out_proj"]
+    with jax.named_scope("ssm"):
+        B, S, H = h.shape
+        di, N, nh = arch.d_inner, arch.ssm_state, arch.ssm_n_heads
+        hp = arch.ssm_headdim
+        proj = h @ p["in_proj"]
+        z, xbc, dtr = jnp.split(proj, [di, 2 * di + 2 * N], axis=-1)
+        # causal depthwise conv over (x, B, C)
+        K = arch.conv_width
+        padded = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+        conv = sum(padded[:, k:k + S] * p["conv_w"][k] for k in range(K)) + p["conv_b"]
+        xbc = silu(conv).astype(h.dtype)
+        xs, Bm, Cm = jnp.split(xbc, [di, di + N], axis=-1)
+        dt = jax.nn.softplus(dtr.astype(jnp.float32) + p["dt_bias"])
+        A = -jnp.exp(p["A_log"])
+        y = ssd_scan(xs.reshape(B, S, nh, hp), dt, A, Bm, Cm, chunk=cfg.ssd_chunk)
+        y = y + p["D"].astype(y.dtype)[None, None, :, None] * xs.reshape(B, S, nh, hp)
+        y = y.reshape(B, S, di)
+        y = rmsnorm(y * silu(z), p["ssm_norm"])
+        return y @ p["out_proj"]
 
 
 def _run_ffn(arch: ArchConfig, lp, x: jax.Array, cfg: RunCfg):
@@ -210,28 +212,30 @@ def _run_ffn(arch: ArchConfig, lp, x: jax.Array, cfg: RunCfg):
     if not (arch.d_ff or arch.n_experts):
         return jnp.zeros_like(x), _zero_aux(arch)
     B, S, H = x.shape
-    h2 = rmsnorm(x, lp["norm2"])
     if arch.n_experts:
-        if cfg.mesh is not None:
-            # shard_map expert parallelism (§Perf iter. 6): local dispatch
-            # per expert rank + one psum combine — avoids GSPMD's one-hot-
-            # matmul synthesis for cross-shard scatter (13-17x flops)
-            from .layers import moe_ep
-            h2 = _cst(h2, cfg, (cfg.batch_axes, None, None))
-            out, aux = moe_ep(h2.reshape(B * S, H), lp["moe"], arch.top_k,
-                              cfg.mesh, cfg.capacity_factor,
-                              gated=arch.mlp == "gated_silu",
-                              data_axes=cfg.batch_axes,
-                              expert_axis=cfg.expert_axis)
-        else:
-            out, aux = moe(h2.reshape(B * S, H), lp["moe"], arch.top_k,
-                           cfg.capacity_factor, gated=arch.mlp == "gated_silu")
-        return out.reshape(B, S, H), {"moe_drop": aux["drop_fraction"],
-                                      "moe_load_max": aux["load"].max().astype(jnp.float32)}
-    h2 = _cst(h2, cfg, (cfg.batch_axes, None, None))
-    inner_cst = (lambda t: _cst(t, cfg, (cfg.batch_axes, None, "model"))) \
-        if cfg.mesh is not None else None
-    return mlp(h2, lp["mlp"], arch.mlp, constrain=inner_cst), _zero_aux(arch)
+        with jax.named_scope("moe"):
+            h2 = rmsnorm(x, lp["norm2"])
+            if cfg.mesh is not None:
+                # shard_map expert parallelism (§Perf iter. 6): local dispatch
+                # per expert rank + one psum combine — avoids GSPMD's one-hot-
+                # matmul synthesis for cross-shard scatter (13-17x flops)
+                from .layers import moe_ep
+                h2 = _cst(h2, cfg, (cfg.batch_axes, None, None))
+                out, aux = moe_ep(h2.reshape(B * S, H), lp["moe"], arch.top_k,
+                                  cfg.mesh, cfg.capacity_factor,
+                                  gated=arch.mlp == "gated_silu",
+                                  data_axes=cfg.batch_axes,
+                                  expert_axis=cfg.expert_axis)
+            else:
+                out, aux = moe(h2.reshape(B * S, H), lp["moe"], arch.top_k,
+                               cfg.capacity_factor, gated=arch.mlp == "gated_silu")
+            return out.reshape(B, S, H), {"moe_drop": aux["drop_fraction"],
+                                          "moe_load_max": aux["load"].max().astype(jnp.float32)}
+    with jax.named_scope("mlp"):
+        h2 = _cst(rmsnorm(x, lp["norm2"]), cfg, (cfg.batch_axes, None, None))
+        inner_cst = (lambda t: _cst(t, cfg, (cfg.batch_axes, None, "model"))) \
+            if cfg.mesh is not None else None
+        return mlp(h2, lp["mlp"], arch.mlp, constrain=inner_cst), _zero_aux(arch)
 
 
 def _zero_aux(arch: ArchConfig):
@@ -273,7 +277,8 @@ def forward(
         assert embeds is not None, f"{arch.name} takes precomputed embeddings"
         x = embeds.astype(cfg.compute_dtype)
     else:
-        x = params["embed"].astype(cfg.compute_dtype)[tokens]
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(cfg.compute_dtype)[tokens]
     x = _cst(x, cfg, _residual_spec(cfg))
     B, S = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
@@ -284,7 +289,6 @@ def forward(
     cast = lambda t: jax.tree.map(lambda a: a.astype(cfg.compute_dtype)
                                   if a.dtype in (jnp.float32, jnp.bfloat16) and a.ndim > 1
                                   else a, t)
-    layers = cast(params["layers"])
 
     def body(x, lp):
         return _block(arch, cfg, x, lp, positions)
@@ -292,24 +296,27 @@ def forward(
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
 
-    if cfg.scan_layers:
-        x, aux = lax.scan(body, x, layers)
-        aux = jax.tree.map(jnp.mean, aux)
-    else:
-        aux = _zero_aux(arch)
-        L = arch.num_layers
-        for i in range(L):
-            lp = jax.tree.map(lambda a: a[i], layers)
-            x, aux_i = body(x, lp)
-            aux = jax.tree.map(lambda a, b: a + b / L, aux, aux_i)
+    with jax.named_scope("layer_scan"):
+        layers = cast(params["layers"])
+        if cfg.scan_layers:
+            x, aux = lax.scan(body, x, layers)
+            aux = jax.tree.map(jnp.mean, aux)
+        else:
+            aux = _zero_aux(arch)
+            L = arch.num_layers
+            for i in range(L):
+                lp = jax.tree.map(lambda a: a[i], layers)
+                x, aux_i = body(x, lp)
+                aux = jax.tree.map(lambda a, b: a + b / L, aux, aux_i)
 
-    if logits_positions == "last":
-        x = x[:, -1:]                       # prefill: next-token logits only
-    x = rmsnorm(x, params["final_norm"].astype(cfg.compute_dtype))
-    logits = x @ params["lm_head"].astype(cfg.compute_dtype)
-    logits = _cst(logits, cfg, (cfg.batch_axes, None, "model"))  # vocab-sharded
-    if cfg.logits_fp32:
-        logits = logits.astype(jnp.float32)
+    with jax.named_scope("head"):
+        if logits_positions == "last":
+            x = x[:, -1:]                       # prefill: next-token logits only
+        x = rmsnorm(x, params["final_norm"].astype(cfg.compute_dtype))
+        logits = x @ params["lm_head"].astype(cfg.compute_dtype)
+        logits = _cst(logits, cfg, (cfg.batch_axes, None, "model"))  # vocab-sharded
+        if cfg.logits_fp32:
+            logits = logits.astype(jnp.float32)
     return logits, aux
 
 
@@ -325,16 +332,17 @@ def loss_fn(
                           tokens=batch.get("tokens"),
                           embeds=batch.get("embeds"), cfg=cfg)
     labels = batch["labels"]
-    # logsumexp form: avoids materialising a second logits-sized
-    # log_softmax buffer; the vocab reduction stays sharded under GSPMD
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = lse - ll
-    mask = batch.get("loss_mask")
-    if mask is None:
-        loss = nll.mean()
-    else:
-        loss = (nll * mask).sum() / jnp.clip(mask.sum(), 1.0)
+    with jax.named_scope("loss"):
+        # logsumexp form: avoids materialising a second logits-sized
+        # log_softmax buffer; the vocab reduction stays sharded under GSPMD
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        nll = lse - ll
+        mask = batch.get("loss_mask")
+        if mask is None:
+            loss = nll.mean()
+        else:
+            loss = (nll * mask).sum() / jnp.clip(mask.sum(), 1.0)
     metrics = {"loss": loss, **aux}
     if arch.n_experts:
         loss = loss + 0.0 * aux.get("moe_drop", 0.0)  # keep aux alive
@@ -367,39 +375,42 @@ def init_cache(arch: ArchConfig, batch: int, max_len: int, cfg: RunCfg = RunCfg(
 def _decode_attn(arch: ArchConfig, p, h, c, pos, cfg):
     B = h.shape[0]
     nh, nkv, hd = arch.n_heads, arch.n_kv, arch.head_dim
-    q = (h @ p["wq"]).reshape(B, 1, nh, hd)
-    k = (h @ p["wk"]).reshape(B, 1, nkv, hd)
-    v = (h @ p["wv"]).reshape(B, 1, nkv, hd)
-    posb = jnp.broadcast_to(pos[None, None], (B, 1))
-    q, k = rope(q, posb), rope(k, posb)
-    span = c["k"].shape[1]
-    slot = pos % span if arch.window else pos
-    k_cache = lax.dynamic_update_slice_in_dim(c["k"], k, slot, axis=1)
-    v_cache = lax.dynamic_update_slice_in_dim(c["v"], v, slot, axis=1)
-    cache_len = jnp.minimum(pos + 1, span)
-    o = decode_attention(q, k_cache, v_cache, cache_len)
-    return o.reshape(B, 1, nh * hd) @ p["wo"], {"k": k_cache, "v": v_cache}
+    with jax.named_scope("attn_proj"):
+        q = (h @ p["wq"]).reshape(B, 1, nh, hd)
+        k = (h @ p["wk"]).reshape(B, 1, nkv, hd)
+        v = (h @ p["wv"]).reshape(B, 1, nkv, hd)
+        posb = jnp.broadcast_to(pos[None, None], (B, 1))
+        q, k = rope(q, posb), rope(k, posb)
+        span = c["k"].shape[1]
+        slot = pos % span if arch.window else pos
+        with jax.named_scope("kv_write"):
+            k_cache = lax.dynamic_update_slice_in_dim(c["k"], k, slot, axis=1)
+            v_cache = lax.dynamic_update_slice_in_dim(c["v"], v, slot, axis=1)
+        cache_len = jnp.minimum(pos + 1, span)
+        o = decode_attention(q, k_cache, v_cache, cache_len)
+        return o.reshape(B, 1, nh * hd) @ p["wo"], {"k": k_cache, "v": v_cache}
 
 
 def _decode_ssm(arch: ArchConfig, p, h, c, cfg):
-    B = h.shape[0]
-    di, N, nh, hp = arch.d_inner, arch.ssm_state, arch.ssm_n_heads, arch.ssm_headdim
-    proj = (h @ p["in_proj"])[:, 0]                        # [B, d_in_proj]
-    z, xbc, dtr = jnp.split(proj, [di, 2 * di + 2 * N], axis=-1)
-    # streaming causal conv: state holds last K-1 inputs
-    K = arch.conv_width
-    hist = jnp.concatenate([c["conv"], xbc[:, None]], axis=1)   # [B,K,conv_dim]
-    conv = (hist * p["conv_w"]).sum(axis=1) + p["conv_b"]
-    new_conv_state = hist[:, 1:]
-    xbc_a = silu(conv).astype(h.dtype)
-    xs, Bm, Cm = jnp.split(xbc_a, [di, di + N], axis=-1)
-    dt = jax.nn.softplus(dtr.astype(jnp.float32) + p["dt_bias"])
-    A = -jnp.exp(p["A_log"])
-    y, new_state = ssm_decode_step(xs.reshape(B, nh, hp), dt, A, Bm, Cm, c["ssm"])
-    y = y + p["D"].astype(y.dtype)[None, :, None] * xs.reshape(B, nh, hp)
-    y = y.reshape(B, 1, di)
-    y = rmsnorm(y * silu(z)[:, None], p["ssm_norm"])
-    return y @ p["out_proj"], {"conv": new_conv_state, "ssm": new_state}
+    with jax.named_scope("ssm"):
+        B = h.shape[0]
+        di, N, nh, hp = arch.d_inner, arch.ssm_state, arch.ssm_n_heads, arch.ssm_headdim
+        proj = (h @ p["in_proj"])[:, 0]                        # [B, d_in_proj]
+        z, xbc, dtr = jnp.split(proj, [di, 2 * di + 2 * N], axis=-1)
+        # streaming causal conv: state holds last K-1 inputs
+        K = arch.conv_width
+        hist = jnp.concatenate([c["conv"], xbc[:, None]], axis=1)   # [B,K,conv_dim]
+        conv = (hist * p["conv_w"]).sum(axis=1) + p["conv_b"]
+        new_conv_state = hist[:, 1:]
+        xbc_a = silu(conv).astype(h.dtype)
+        xs, Bm, Cm = jnp.split(xbc_a, [di, di + N], axis=-1)
+        dt = jax.nn.softplus(dtr.astype(jnp.float32) + p["dt_bias"])
+        A = -jnp.exp(p["A_log"])
+        y, new_state = ssm_decode_step(xs.reshape(B, nh, hp), dt, A, Bm, Cm, c["ssm"])
+        y = y + p["D"].astype(y.dtype)[None, :, None] * xs.reshape(B, nh, hp)
+        y = y.reshape(B, 1, di)
+        y = rmsnorm(y * silu(z)[:, None], p["ssm_norm"])
+        return y @ p["out_proj"], {"conv": new_conv_state, "ssm": new_state}
 
 
 def decode_step(
@@ -415,7 +426,8 @@ def decode_step(
     if arch.embeds_input:
         x = embeds[:, None].astype(cfg.compute_dtype)
     else:
-        x = params["embed"].astype(cfg.compute_dtype)[tokens][:, None]
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(cfg.compute_dtype)[tokens][:, None]
 
     cast = lambda t: jax.tree.map(lambda a: a.astype(cfg.compute_dtype)
                                   if a.dtype in (jnp.float32, jnp.bfloat16) and a.ndim > 1
@@ -442,17 +454,19 @@ def decode_step(
         delta, _ = _run_ffn(arch, lp, x, cfg)
         return x + delta, new_c
 
-    if cfg.scan_layers:
-        x, new_cache = lax.scan(body, x, (params["layers"], cache))
-    else:
-        L = arch.num_layers
-        new_layers = []
-        for i in range(L):
-            lp = jax.tree.map(lambda a: a[i], params["layers"])
-            ci = jax.tree.map(lambda a: a[i], cache)
-            x, nc = body(x, (lp, ci))
-            new_layers.append(nc)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_layers)
-    x = rmsnorm(x, params["final_norm"].astype(cfg.compute_dtype))
-    logits = (x @ params["lm_head"].astype(cfg.compute_dtype))[:, 0]
-    return logits.astype(jnp.float32), new_cache
+    with jax.named_scope("layer_scan"):
+        if cfg.scan_layers:
+            x, new_cache = lax.scan(body, x, (params["layers"], cache))
+        else:
+            L = arch.num_layers
+            new_layers = []
+            for i in range(L):
+                lp = jax.tree.map(lambda a: a[i], params["layers"])
+                ci = jax.tree.map(lambda a: a[i], cache)
+                x, nc = body(x, (lp, ci))
+                new_layers.append(nc)
+            new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_layers)
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_norm"].astype(cfg.compute_dtype))
+        logits = (x @ params["lm_head"].astype(cfg.compute_dtype))[:, 0]
+        return logits.astype(jnp.float32), new_cache

@@ -67,40 +67,41 @@ def apply_optimizer(
 ) -> Tuple[Any, Dict, Dict]:
     """Returns (new_params, new_state, metrics). Math in fp32, storage at
     param/moment dtypes."""
-    step = state["step"] + 1
-    lr = lr_at(cfg, step)
+    with jax.named_scope("optimizer"):
+        step = state["step"] + 1
+        lr = lr_at(cfg, step)
 
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9)) if cfg.grad_clip > 0 else 1.0
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9)) if cfg.grad_clip > 0 else 1.0
 
-    if cfg.name == "sgd":
-        def upd(p, g):
+        if cfg.name == "sgd":
+            def upd(p, g):
+                g32 = g.astype(jnp.float32) * scale
+                return (p.astype(jnp.float32) - lr * g32).astype(p.dtype)
+            new_params = jax.tree.map(upd, params, grads)
+            new_state = {**state, "step": step}
+            return new_params, new_state, {"lr": lr, "grad_norm": gnorm}
+
+        b1, b2 = cfg.b1, cfg.b2
+        c1 = 1.0 - b1 ** step.astype(jnp.float32)
+        c2 = 1.0 - b2 ** step.astype(jnp.float32)
+
+        def upd(p, g, m, v):
             g32 = g.astype(jnp.float32) * scale
-            return (p.astype(jnp.float32) - lr * g32).astype(p.dtype)
-        new_params = jax.tree.map(upd, params, grads)
-        new_state = {**state, "step": step}
+            m32 = b1 * m.astype(jnp.float32) + (1 - b1) * g32
+            v32 = b2 * v.astype(jnp.float32) + (1 - b2) * g32 * g32
+            mhat = m32 / c1
+            vhat = v32 / c2
+            p32 = p.astype(jnp.float32)
+            step_dir = mhat / (jnp.sqrt(vhat) + cfg.eps)
+            if cfg.weight_decay > 0 and p.ndim >= 2:   # decay matrices only
+                step_dir = step_dir + cfg.weight_decay * p32
+            return ((p32 - lr * step_dir).astype(p.dtype),
+                    m32.astype(cfg.moment_dtype), v32.astype(cfg.moment_dtype))
+
+        out = jax.tree.map(upd, params, grads, state["m"], state["v"])
+        new_params = jax.tree.map(lambda t: t[0], out, is_leaf=lambda x: isinstance(x, tuple))
+        new_m = jax.tree.map(lambda t: t[1], out, is_leaf=lambda x: isinstance(x, tuple))
+        new_v = jax.tree.map(lambda t: t[2], out, is_leaf=lambda x: isinstance(x, tuple))
+        new_state = {"m": new_m, "v": new_v, "step": step}
         return new_params, new_state, {"lr": lr, "grad_norm": gnorm}
-
-    b1, b2 = cfg.b1, cfg.b2
-    c1 = 1.0 - b1 ** step.astype(jnp.float32)
-    c2 = 1.0 - b2 ** step.astype(jnp.float32)
-
-    def upd(p, g, m, v):
-        g32 = g.astype(jnp.float32) * scale
-        m32 = b1 * m.astype(jnp.float32) + (1 - b1) * g32
-        v32 = b2 * v.astype(jnp.float32) + (1 - b2) * g32 * g32
-        mhat = m32 / c1
-        vhat = v32 / c2
-        p32 = p.astype(jnp.float32)
-        step_dir = mhat / (jnp.sqrt(vhat) + cfg.eps)
-        if cfg.weight_decay > 0 and p.ndim >= 2:   # decay matrices only
-            step_dir = step_dir + cfg.weight_decay * p32
-        return ((p32 - lr * step_dir).astype(p.dtype),
-                m32.astype(cfg.moment_dtype), v32.astype(cfg.moment_dtype))
-
-    out = jax.tree.map(upd, params, grads, state["m"], state["v"])
-    new_params = jax.tree.map(lambda t: t[0], out, is_leaf=lambda x: isinstance(x, tuple))
-    new_m = jax.tree.map(lambda t: t[1], out, is_leaf=lambda x: isinstance(x, tuple))
-    new_v = jax.tree.map(lambda t: t[2], out, is_leaf=lambda x: isinstance(x, tuple))
-    new_state = {"m": new_m, "v": new_v, "step": step}
-    return new_params, new_state, {"lr": lr, "grad_norm": gnorm}

@@ -153,3 +153,24 @@ def test_train_restart_resumes_deterministically(tmp_path):
     # the resumed tail must match the continuous run's tail
     np.testing.assert_allclose(losses_resumed[-3:], losses_full[-3:],
                                rtol=2e-4, atol=2e-4)
+
+
+def test_train_loop_writes_the_benchmark_spans(tmp_path):
+    """Under the profiler, each step of ``train_loop`` is a ``train``
+    step annotation holding the chip benchmark's three host spans."""
+    from collections import Counter
+    from jax.profiler import ProfileData
+    arch = scale_arch(get_config("yi-6b"), "tiny")
+    cfg = TrainCfg(run=RunCfg(q_chunk=0, remat=False))
+    data_cfg = DataCfg(seq_len=16, global_batch=2, num_microbatches=1)
+    with jax.profiler.trace(str(tmp_path)):
+        train_loop(arch, cfg, data_cfg, steps=3, log_fn=lambda *_: None)
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    profile = ProfileData.from_file(str(path))
+    names = ("train", "data_wait", "dispatch", "loss_fetch")
+    counts = Counter(e.name for plane in profile.planes if plane.name.startswith("/host:")
+                     for line in plane.lines for e in line.events if e.name in names)
+    assert counts == {name: 3 for name in names}
+    steps = sorted(dict(e.stats)["step_num"] for plane in profile.planes
+                   for line in plane.lines for e in line.events if e.name == "train")
+    assert steps == [0, 1, 2]
