@@ -18,6 +18,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..configs.base import ArchConfig
 from .layers import (
@@ -372,7 +373,19 @@ def init_cache(arch: ArchConfig, batch: int, max_len: int, cfg: RunCfg = RunCfg(
     return cache
 
 
-def _decode_attn(arch: ArchConfig, p, h, c, pos, cfg):
+def _row_major(tree):
+    """Pins each array to its default row-major layout. The decode loop
+    carries the K/V cache under this pin: left free, XLA lays the carry
+    out for the attention read and copies the whole cache into and out
+    of that layout on every step."""
+    return jax.tree.map(
+        lambda a: with_layout_constraint(a, Layout(tuple(range(a.ndim)))), tree)
+
+
+def _decode_attn(arch: ArchConfig, p, h, kv, layer, pos, cfg):
+    """Attention for one new token. ``kv`` is the layer-stacked K/V cache
+    ``[L,B,span,nkv,hd]``: this step's k and v are written at ``layer``'s
+    slot in place, and the layer reads back its own span."""
     B = h.shape[0]
     nh, nkv, hd = arch.n_heads, arch.n_kv, arch.head_dim
     with jax.named_scope("attn_proj"):
@@ -381,14 +394,17 @@ def _decode_attn(arch: ArchConfig, p, h, c, pos, cfg):
         v = (h @ p["wv"]).reshape(B, 1, nkv, hd)
         posb = jnp.broadcast_to(pos[None, None], (B, 1))
         q, k = rope(q, posb), rope(k, posb)
-        span = c["k"].shape[1]
+        span = kv["k"].shape[2]
         slot = pos % span if arch.window else pos
         with jax.named_scope("kv_write"):
-            k_cache = lax.dynamic_update_slice_in_dim(c["k"], k, slot, axis=1)
-            v_cache = lax.dynamic_update_slice_in_dim(c["v"], v, slot, axis=1)
+            kv = {"k": lax.dynamic_update_slice(kv["k"], k[None], (layer, 0, slot, 0, 0)),
+                  "v": lax.dynamic_update_slice(kv["v"], v[None], (layer, 0, slot, 0, 0))}
+        with jax.named_scope("attention"):
+            k_cache, v_cache = (lax.dynamic_index_in_dim(kv[n], layer, keepdims=False)
+                                for n in ("k", "v"))
         cache_len = jnp.minimum(pos + 1, span)
         o = decode_attention(q, k_cache, v_cache, cache_len)
-        return o.reshape(B, 1, nh * hd) @ p["wo"], {"k": k_cache, "v": v_cache}
+        return o.reshape(B, 1, nh * hd) @ p["wo"], kv
 
 
 def _decode_ssm(arch: ArchConfig, p, h, c, cfg):
@@ -433,40 +449,44 @@ def decode_step(
                                   if a.dtype in (jnp.float32, jnp.bfloat16) and a.ndim > 1
                                   else a, t)
 
-    def body(x, scanned):
-        lp, c = scanned
+    # The K/V cache is a loop carry written in place, one position a
+    # layer; only the SSM states, rewritten whole each step, are outputs.
+    kv = {n: cache[n] for n in ("k", "v") if n in cache}
+    states = {n: cache[n] for n in ("conv", "ssm") if n in cache}
+
+    def body(carry, scanned):
+        x, kv = carry
+        lp, c, layer = scanned
         lp = cast(lp)
         h = rmsnorm(x, lp["norm1"])
         new_c = {}
         if arch.block == "attn":
-            o, kv = _decode_attn(arch, lp["attn"], h, c, pos, cfg)
+            o, kv = _decode_attn(arch, lp["attn"], h, kv, layer, pos, cfg)
             x = x + o
-            new_c.update(kv)
         elif arch.block == "ssm":
-            o, sc = _decode_ssm(arch, lp["ssm"], h, c, cfg)
+            o, new_c = _decode_ssm(arch, lp["ssm"], h, c, cfg)
             x = x + o
-            new_c.update(sc)
         else:
-            a, kv = _decode_attn(arch, lp["attn"], h, c, pos, cfg)
-            s, sc = _decode_ssm(arch, lp["ssm"], h, c, cfg)
+            a, kv = _decode_attn(arch, lp["attn"], h, kv, layer, pos, cfg)
+            s, new_c = _decode_ssm(arch, lp["ssm"], h, c, cfg)
             x = x + 0.5 * (a + s)
-            new_c.update(kv); new_c.update(sc)
         delta, _ = _run_ffn(arch, lp, x, cfg)
-        return x + delta, new_c
+        return (x + delta, _row_major(kv)), new_c
 
     with jax.named_scope("layer_scan"):
+        L = arch.num_layers
         if cfg.scan_layers:
-            x, new_cache = lax.scan(body, x, (params["layers"], cache))
+            (x, kv), states = lax.scan(body, (x, kv),
+                                       (params["layers"], states, jnp.arange(L)))
         else:
-            L = arch.num_layers
-            new_layers = []
+            new_states = []
             for i in range(L):
                 lp = jax.tree.map(lambda a: a[i], params["layers"])
-                ci = jax.tree.map(lambda a: a[i], cache)
-                x, nc = body(x, (lp, ci))
-                new_layers.append(nc)
-            new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_layers)
+                c = jax.tree.map(lambda a: a[i], states)
+                (x, kv), nc = body((x, kv), (lp, c, i))
+                new_states.append(nc)
+            states = jax.tree.map(lambda *xs: jnp.stack(xs), *new_states)
     with jax.named_scope("head"):
         x = rmsnorm(x, params["final_norm"].astype(cfg.compute_dtype))
         logits = (x @ params["lm_head"].astype(cfg.compute_dtype))[:, 0]
-        return logits.astype(jnp.float32), new_cache
+        return logits.astype(jnp.float32), {**kv, **states}

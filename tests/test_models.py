@@ -4,6 +4,7 @@ correct shapes; decode matches teacher-forced forward (strong AR-cache
 correctness check)."""
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ from repro.models import (
     init_params,
     loss_fn,
 )
+from repro.serving.serve import make_serve_step
 
 CFG = RunCfg(q_chunk=0, remat=False)
 KEY = jax.random.PRNGKey(0)
@@ -49,16 +51,20 @@ def test_arch_smoke_forward_and_grad(name):
     assert np.isfinite(gn) and gn > 0
 
 
-@pytest.mark.parametrize("name", ["yi-6b", "granite-moe-3b-a800m",
-                                  "mamba2-2.7b", "hymba-1.5b",
-                                  "llava-next-34b"])
-def test_decode_matches_teacher_forced_forward(name):
+@pytest.mark.parametrize("name,window", [
+    *(pytest.param(n, 0, id=n) for n in ["yi-6b", "granite-moe-3b-a800m", "mamba2-2.7b",
+                                          "hymba-1.5b", "llava-next-34b"]),
+    pytest.param("hymba-1.5b", 5, id="hymba-1.5b-window5"),
+    pytest.param("yi-6b", 5, id="yi-6b-window5"),
+])
+def test_decode_matches_teacher_forced_forward(name, window):
     """decode_step over a prompt must reproduce forward()'s next-token
     logits at every position (KV cache + SSM state correctness).
+    ``window`` 0 is full attention; a window shorter than the 12-token
+    prompt makes the ring buffer's slot wrap, so a stale entry would show.
     MoE capacity is batch-dependent, so use a drop-free capacity factor —
     with drops, decode-vs-forward divergence is expected MoE semantics."""
-    arch = scale_arch(get_config(name), "tiny")
-    arch = dataclasses.replace(arch, window=0 if arch.window else 0)  # full attn
+    arch = dataclasses.replace(scale_arch(get_config(name), "tiny"), window=window)
     cfg = dataclasses.replace(CFG, capacity_factor=8.0)
     params = init_params(arch, KEY, cfg)
     B, S = 2, 12
@@ -70,6 +76,8 @@ def test_decode_matches_teacher_forced_forward(name):
         ref_logits, _ = forward(arch, params, tokens=tokens, cfg=cfg)
 
     cache = init_cache(arch, B, S + 4, cfg)
+    if window:
+        assert cache["k"].shape[2] == window < S
     outs = []
     for t in range(S):
         if arch.embeds_input:
@@ -83,6 +91,62 @@ def test_decode_matches_teacher_forced_forward(name):
     np.testing.assert_allclose(np.asarray(dec_logits, np.float32),
                                np.asarray(ref_logits, np.float32),
                                rtol=2e-2, atol=2e-2)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+def test_serve_step_writes_the_kv_cache_in_place(scan):
+    """The serve step carries the layer-stacked K/V cache through the
+    layers and writes one position a layer into it: no stacked scan
+    output and no copy has the cache's shape, and the compiled step
+    aliases the donated cache to its output. Two donated steps give the
+    logits and cache of the undonated decode_step, and change the cache
+    only at the two positions written."""
+    arch = scale_arch(get_config("yi-6b"), "tiny")
+    # an f32 cache: the CPU backend computes a bf16 update in f32 and copies
+    # the carry for it; test_tpu_compile.py checks the bf16 cache on a v5e
+    cfg = dataclasses.replace(CFG, scan_layers=scan, compute_dtype=jnp.float32)
+    params = init_params(arch, KEY, cfg)
+    B, span, pos = 3, 8, 5
+    cache = {n: jax.random.normal(jax.random.PRNGKey(i + 1), a.shape, a.dtype)
+             for i, (n, a) in enumerate(init_cache(arch, B, span, cfg).items())}
+    shape = cache["k"].shape
+    assert shape == (arch.num_layers, B, span, arch.n_kv, arch.head_dim)
+    tokens = jax.random.randint(KEY, (2, B), 0, arch.vocab)
+    serve = make_serve_step(arch, cfg)
+
+    jaxpr = jax.make_jaxpr(serve)(params, cache, tokens[0], jnp.int32(pos)).jaxpr
+    scans = [e for e in _eqns(jaxpr) if e.primitive.name == "scan"]
+    assert len(scans) == int(scan)
+    assert all(v.aval.shape != shape
+               for e in scans for v in e.outvars[e.params["num_carry"]:])
+    makers = {e.primitive.name for e in _eqns(jaxpr)
+              if any(getattr(v.aval, "shape", None) == shape for v in e.outvars)}
+    assert makers <= {"dynamic_update_slice", "layout_constraint", "scan", "jit"}, makers
+
+    hlo = serve.lower(params, cache, tokens[0], jnp.int32(pos)).compile().as_text()
+    n = len(jax.tree.leaves(params))
+    assert f"{{2}}: ({n}, {{}}, may-alias), {{3}}: ({n + 1}, {{}}, may-alias)" in hlo
+    full = "f32[" + ",".join(map(str, shape)) + "]"
+    assert not [line for line in hlo.splitlines() if full in line and " copy(" in line]
+
+    undonated = jax.jit(partial(decode_step, arch, cfg=cfg))
+    want, got = cache, jax.tree.map(jnp.copy, cache)
+    for t in range(2):
+        want_logits, want = undonated(params, want, tokens=tokens[t], pos=jnp.int32(pos + t))
+        _, got_logits, got = serve(params, got, tokens[t], jnp.int32(pos + t))
+        np.testing.assert_array_equal(np.asarray(got_logits), np.asarray(want_logits))
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got[n]), np.asarray(want[n]))
+        changed = np.any(np.asarray(got[n] != cache[n]), axis=(0, 1, 3, 4))
+        assert np.flatnonzero(changed).tolist() == [pos, pos + 1]
 
 
 def test_hubert_encoder_no_decode():

@@ -1,13 +1,17 @@
-"""Compile the Pallas kernels for a described TPU v5e (no chip needed).
+"""Compile the Pallas kernels and the serve step for a described TPU v5e
+(no chip needed).
 
 The TPU compiler is installed wherever libtpu is, and it compiles for a
 topology that is described rather than attached. That catches what the
 interpret-mode tests cannot: block shapes that break the (8, 128) tiling
-rule, ops Mosaic cannot lower, and VMEM overuse. Shapes are the real
-widths of the configs each kernel serves.
+rule, ops Mosaic cannot lower, VMEM overuse, and the TPU's own layout
+choices, which can add whole-array copies. Shapes are the real widths of
+the configs each kernel serves.
 """
 
+import dataclasses
 import os
+import re
 from functools import partial
 
 import jax
@@ -15,9 +19,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.models.lm import RunCfg, init_cache, init_params
+from repro.serving.serve import make_serve_step
 
 
 @pytest.fixture(scope="module")
@@ -74,3 +81,27 @@ def test_ssd_scan_compiles_for_v5e(shape):
     _compile(partial(ssd_scan_pallas, chunk=256),
              shape((B, nh, S, hp)), shape((B, nh, S), jnp.float32),
              shape((nh,), jnp.float32), shape((B, S, N)), shape((B, S, N)))
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+def test_serve_step_updates_the_kv_cache_in_place_on_v5e(shape, scan):
+    """yi-6b at full width, 2 layers, batch 16 over a 1280-position
+    cache: the compiled serve step aliases the donated K/V cache to its
+    output, makes no copy of it, and needs less scratch than one layer's
+    K span."""
+    arch = dataclasses.replace(get_config("yi-6b"), num_layers=2)
+    cfg = RunCfg(param_dtype=jnp.bfloat16, scan_layers=scan)
+    B, span = 16, 1280
+    abstract = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params = abstract(jax.eval_shape(lambda: init_params(arch, jax.random.PRNGKey(0), cfg)))
+    cache = abstract(jax.eval_shape(lambda: init_cache(arch, B, span, cfg)))
+    compiled = make_serve_step(arch, cfg).lower(
+        params, cache, shape((B,), jnp.int32), shape((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    n = len(jax.tree.leaves(params))
+    assert f"{{2}}: ({n}, {{}}, may-alias), {{3}}: ({n + 1}, {{}}, may-alias)" in hlo
+    full = re.escape(f"bf16[2,{B},{span},{arch.n_kv},{arch.head_dim}]")
+    made = re.findall(rf"%([\w.-]+) = {full}\{{[^}}]*\}} ([\w-]+)\(", hlo)
+    assert made and not [m for m in made if m[1] == "copy" or m[0].startswith("copy")]
+    layer_k_bytes = B * span * arch.n_kv * arch.head_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
