@@ -62,36 +62,130 @@ def load_cell(name: str, spec: Optional[Dict] = None, root: Path = ROOT) -> Cell
                 limits=load_json(limits_path) if limits_path.exists() else None)
 
 
+# Keys of a configuration file that say nothing about the model's
+# equations: provenance, the dtypes the jobs choose, and run settings.
+METADATA = frozenset({"name", "source", "paper", "reference", "published", "why_changed",
+                      "assumed", "deployment", "dtype", "compute_dtype", "model_type",
+                      "max_position_embeddings", "capacity_factor"})
+
+# Published names of architecture keys -> ``ArchConfig`` field. Any other
+# key reaches ``ArchConfig`` by its own name.
+PUBLISHED_NAMES = {
+    "hidden_size": "d_model", "num_hidden_layers": "num_layers",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv",
+    "intermediate_size": "d_ff", "vocab_size": "vocab", "hidden_act": "mlp",
+    "tie_word_embeddings": "tie_embeddings", "sliding_window": "window",
+    "num_local_experts": "n_experts", "num_experts_per_tok": "top_k",
+    "state_size": "ssm_state", "mamba_d_state": "ssm_state",
+    "conv_kernel": "conv_width", "mamba_d_conv": "conv_width",
+    "mamba_d_head": "ssm_headdim", "expand": "d_inner", "mamba_expand": "d_inner",
+}
+ACTIVATIONS = {"silu": "gated_silu", "relu2": "squared_relu"}
+# Values the program takes in another form than the file gives them.
+CONVERT = {
+    "hidden_act": lambda v, c: ACTIVATIONS[v],
+    "sliding_window": lambda v, c: v or 0,      # null: no window
+    "expand": lambda v, c: v * c["hidden_size"],
+    "mamba_expand": lambda v, c: v * c["hidden_size"],
+}
+
+
+def head_dim(config: Dict) -> int:
+    return config.get("head_dim") or config["hidden_size"] // config["num_attention_heads"]
+
+
+# Keys the program has no field for, each with the value at which the
+# published model is the one the program runs (a function of the file).
+PLAIN = {
+    "rope_theta": lambda c: 10000.0,
+    "rms_norm_eps": lambda c: 1e-5,
+    "embedding_multiplier": lambda c: 1.0,
+    "residual_multiplier": lambda c: 1.0,
+    "logits_scaling": lambda c: 1.0,
+    "attention_multiplier": lambda c: head_dim(c) ** -0.5,
+}
+
+
 def arch_of(config: Dict):
-    """The program's ``ArchConfig`` for a configuration file."""
+    """The program's ``ArchConfig`` for a configuration file. Every key is
+    metadata (``METADATA``), reaches an ``ArchConfig`` field (by its
+    published name in ``PUBLISHED_NAMES`` or by the field's own name), or
+    has no field and holds the value that leaves the model as the program
+    runs it (``PLAIN``); any other key, or value, stops the run."""
+    from dataclasses import fields
     from repro.configs.base import ArchConfig
-    if config.get("hidden_act") != "silu":
-        raise ValueError(f"unsupported hidden_act {config.get('hidden_act')!r}")
+    names = {f.name for f in fields(ArchConfig)}
     experts = config.get("num_local_experts", 0)
-    h, nh = config["hidden_size"], config["num_attention_heads"]
-    return ArchConfig(
-        name=config["name"], family="moe" if experts else "dense",
-        num_layers=config["num_hidden_layers"], d_model=h, n_heads=nh,
-        n_kv=config["num_key_value_heads"], d_ff=config["intermediate_size"],
-        vocab=config["vocab_size"], head_dim=config.get("head_dim") or h // nh,
-        mlp="gated_silu", tie_embeddings=config["tie_word_embeddings"],
-        n_experts=experts, top_k=config.get("num_experts_per_tok", 0),
-        d_ff_expert=config["intermediate_size"] if experts else 0,
-        source=config["source"])
+    out = {"name": config["name"], "source": config["source"],
+           "family": "moe" if experts else "dense",
+           "d_ff_expert": config["intermediate_size"] if experts else 0}
+    for key, value in config.items():
+        field_name = PUBLISHED_NAMES.get(key, key)
+        if key in METADATA:
+            continue
+        if key == "hidden_act" and value not in ACTIVATIONS:
+            raise ValueError(f"hidden_act = {value!r}: the program has no such MLP")
+        if field_name in names:
+            out[field_name] = CONVERT.get(key, lambda v, c: v)(value, config)
+        elif key in PLAIN and value != PLAIN[key](config):
+            raise ValueError(f"{key} = {value!r}: the program has no {key}; it runs "
+                             f"{PLAIN[key](config)!r}")
+        elif key not in PLAIN:
+            raise ValueError(f"{key}: neither metadata nor a key the program takes")
+    arch = ArchConfig(**out)
+    if arch.tie_embeddings:
+        import jax.numpy as jnp
+        import weights
+        if "['lm_head']" in weights.param_shapes(arch, jnp.float32):
+            raise ValueError("tie_word_embeddings = True: the program keeps a separate "
+                             "lm_head")
+    return arch
+
+
+def run_cfg(config: Dict, **settings):
+    """The program's ``RunCfg`` with ``settings`` and the file's run
+    settings: its ``capacity_factor``, the same number the reference
+    reads. An expert model without one routes without drops, which the
+    program takes as ``capacity_factor=None`` once it declares it so."""
+    import typing
+    from repro.models.lm import RunCfg
+    if "capacity_factor" in config:
+        settings["capacity_factor"] = config["capacity_factor"]
+    elif config.get("num_local_experts"):
+        if type(None) not in typing.get_args(typing.get_type_hints(RunCfg)["capacity_factor"]):
+            raise ValueError(f"{config['name']} routes without drops (no capacity_factor); "
+                             "the program's RunCfg.capacity_factor takes no None")
+        settings["capacity_factor"] = None
+    return RunCfg(**settings)
+
+
+def reference_of(config: Dict):
+    """The reference module that the configuration's ``reference`` names,
+    a path under the benchmark's directory."""
+    import importlib.util
+    import sys
+    path = (BENCH_DIR / config["reference"]).resolve()
+    if BENCH_DIR not in path.parents:
+        raise ValueError(f"reference {config['reference']!r} lies outside {BENCH_DIR}")
+    name = "reference_" + "_".join(path.relative_to(BENCH_DIR).with_suffix("").parts)
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        sys.modules[name] = module
+    return sys.modules[name]
 
 
 class Spans:
-    """Host spans by name: total seconds and count. With ``trace`` each
+    """Host spans by name: total seconds. With ``trace`` each
     span is also written into the profiler's trace, on the device's clock."""
 
     def __init__(self, trace: bool = False):
         self.trace = trace
         self.seconds: Dict[str, float] = defaultdict(float)
-        self.count: Dict[str, int] = defaultdict(int)
 
     def reset(self):
         self.seconds.clear()
-        self.count.clear()
 
     @contextmanager
     def __call__(self, name: str):
@@ -100,7 +194,6 @@ class Spans:
         with ann:
             yield
         self.seconds[name] += time.perf_counter() - t0
-        self.count[name] += 1
 
 
 class CompileLog:
@@ -130,6 +223,7 @@ class Outcome:
     numbers: Dict[str, float]             # compared number -> reading
     record: Dict = field(default_factory=dict)   # what per-layer readers read
     memory_peak_bytes: int = 0
+    step_hlo: Optional[str] = None        # the step's compiled HLO text, traced runs
 
 
 def judge(numbers: Dict[str, float], limits: Optional[Dict]) -> tuple:

@@ -9,14 +9,14 @@ For each seed it prints the compared numbers of the program as the
 benchmark's set-up produces them (training: the checked steps; decode: a
 cache fill and ``--turns`` whole turns at the cell's batch). For each
 control seed it also prints the control's numbers, the float32 reference
-against the reference computed in fp8 (``prec="fp8"`` of
-``reference/llama.py``) put in the program's place, and those of faults
-planted in the reference: half of the batch left out of the loss
-(training), or one served token of each session altered and half of the
-sessions left undecoded (decode). Training lines also name the leaf that
-sets ``grad_diff``. Each line is one JSON object. With ``--write-limits`` it then sets each number's limit from
-these readings and writes ``limits/<cell>.json`` (see ``limits_of``).
-Needs a TPU.
+against the reference computed in fp8 (``prec="fp8"`` of the reference
+module that the configuration names) put in the program's place, and
+those of faults planted in the reference: half of the batch left out of
+the loss (training), or one served token of each session altered and half
+of the sessions left undecoded (decode). Training lines also name the leaf
+that sets ``grad_diff``. Each line is one JSON object. With
+``--write-limits`` it then sets each number's limit from these readings
+and writes ``limits/<cell>.json`` (see ``limits_of``). Needs a TPU.
 """
 
 from __future__ import annotations

@@ -29,8 +29,7 @@ import numpy as np
 
 import traffic
 import weights
-from bench import Outcome, arch_of
-from reference import llama
+from bench import Outcome, arch_of, reference_of, run_cfg
 
 MAX_TURNS = 4096
 
@@ -39,13 +38,13 @@ class Server:
     """The compiled serve step with its weights, cache and sessions."""
 
     def __init__(self, cell, seed: int, spans):
-        from repro.models.lm import RunCfg, init_cache
+        from repro.models.lm import init_cache
         from repro.serving.serve import make_serve_step
         t = cell.traffic
         self.arch = arch_of(cell.config)
         self.batch, self.context, self.turn_tokens = t["batch"], t["context"], t["turn_tokens"]
         span = self.context + self.turn_tokens
-        cfg = RunCfg(param_dtype=jnp.bfloat16)
+        cfg = run_cfg(cell.config, param_dtype=jnp.bfloat16)
         self.key = weights.seed_key(seed)
         self.serve = make_serve_step(self.arch, cfg)
         self.params = weights.generate(self.arch, self.key, jnp.bfloat16)
@@ -78,6 +77,12 @@ class Server:
             if on_step():
                 return s + 1
         return self.turn_tokens
+
+    def step_hlo(self) -> str:
+        """The compiled step's HLO text, whose op names the trace's ops carry."""
+        tok = jax.device_put(self.openers[0])
+        return self.serve.lower(self.params, self.cache, tok,
+                                self.positions[0]).compile().as_text()
 
     def close(self):
         self.params = self.cache = None
@@ -126,11 +131,12 @@ def _gaps(logits, chosen):
 
 
 def reference_gaps(cell, seed: int, seqs: np.ndarray, choices: List[np.ndarray]):
-    """For each array of choices ``[n, S]``, the float32 reference's gap
-    at every position, ``[len(choices), n, S]``."""
+    """For each array of choices ``[n, S]``, the gap at every position in
+    the configuration's float32 reference, ``[len(choices), n, S]``."""
     arch = arch_of(cell.config)
     make_layer, make_leaf = _reference_weights(arch, weights.seed_key(seed))
-    rows = llama.position_logits(cell.config, make_layer, make_leaf, seqs)
+    rows = reference_of(cell.config).position_logits(cell.config, make_layer, make_leaf,
+                                                     seqs)
     out = [np.asarray(_gaps(logits, jnp.asarray(np.stack([c[i] for c in choices]))))
            for i, logits in enumerate(rows)]
     return np.stack(out, axis=1)
@@ -140,8 +146,9 @@ def reference_choice(cell, seed: int, seqs: np.ndarray, prec: str) -> np.ndarray
     """The token the reference in ``prec`` puts first at every position."""
     arch = arch_of(cell.config)
     make_layer, make_leaf = _reference_weights(arch, weights.seed_key(seed))
-    return np.stack([np.asarray(jnp.argmax(logits, axis=-1)) for logits in
-                     llama.position_logits(cell.config, make_layer, make_leaf, seqs, prec)])
+    rows = reference_of(cell.config).position_logits(cell.config, make_layer, make_leaf,
+                                                     seqs, prec)
+    return np.stack([np.asarray(jnp.argmax(logits, axis=-1)) for logits in rows])
 
 
 def gap_numbers(gaps: np.ndarray) -> dict:
@@ -184,6 +191,7 @@ def run(cell, seed: int, seconds: float, spans, window_trace, process_start: flo
         raise RuntimeError(f"{len(compiles.events) - first_compile} compiles in the window")
     if not served_turns:
         raise RuntimeError("the window finished no turn; lengthen --seconds")
+    step_hlo = server.step_hlo() if window_trace.directory else None
 
     steps = len(marks) - 1
     step_p95_ms = float(np.percentile(np.diff(marks), 95) * 1e3)
@@ -202,8 +210,7 @@ def run(cell, seed: int, seconds: float, spans, window_trace, process_start: flo
         end_to_end={"decode_tokens_per_s": steps * t["batch"] / window_s,
                     "decode_step_p95_ms": step_p95_ms, "setup_s": setup_s},
         attempted=turns_started * t["batch"], failed=0, numbers=numbers,
-        memory_peak_bytes=peak,
-        record={"steps": steps, "window_s": window_s,
-                "spans": dict(spans.seconds), "span_counts": dict(spans.count),
+        memory_peak_bytes=peak, step_hlo=step_hlo,
+        record={"steps": steps, "window_s": window_s, "spans": dict(spans.seconds),
                 "step_flops": work["flops"], "step_bytes": work["bytes"],
                 "chips": cell.chips, "peak_bytes": peak})

@@ -24,8 +24,7 @@ import numpy as np
 
 import traffic
 import weights
-from bench import Outcome, arch_of, gap, moving_leaves
-from reference import llama
+from bench import Outcome, arch_of, gap, moving_leaves, reference_of, run_cfg
 
 CHECKED_STEPS = 3
 
@@ -41,7 +40,7 @@ class Trainer:
         if t["microbatches"] != 1:
             raise ValueError("the reference follows one microbatch a step")
         self.arch = arch_of(cell.config)
-        cfg = TrainCfg(opt=OptimizerCfg(**t["optimizer"]),
+        cfg = TrainCfg(run=run_cfg(cell.config), opt=OptimizerCfg(**t["optimizer"]),
                        num_microbatches=t["microbatches"])
         self.b1 = cfg.opt.b1
         self.key = weights.seed_key(seed)
@@ -72,6 +71,11 @@ class Trainer:
         return {"losses": losses, "grad": moment, "grad_scale": 1 / (1 - self.b1),
                 "change": change}
 
+    def step_hlo(self) -> str:
+        """The compiled step's HLO text, whose op names the trace's ops carry."""
+        return self.fn.lower(self.params, self.opt_state,
+                             next(self.data)).compile().as_text()
+
     def close(self):
         self.data.close()
         self.params = self.opt_state = None
@@ -80,7 +84,8 @@ class Trainer:
 
 def reference(cell, seed: int, prec: str = "f32",
               row_weights: Optional[np.ndarray] = None) -> Dict:
-    """The reference's readings of the checked steps from ``seed``."""
+    """The readings of the checked steps from ``seed`` of the reference
+    that the configuration names."""
     arch = arch_of(cell.config)
     t = cell.traffic
     key = weights.seed_key(seed)
@@ -88,7 +93,8 @@ def reference(cell, seed: int, prec: str = "f32",
     batches = [traffic.train_rows(seed, s, arch.vocab, t["seq_len"],
                                   t["global_batch"], t["microbatches"])
                for s in range(CHECKED_STEPS)]
-    out = llama.train(cell.config, t["optimizer"], params, batches, prec, row_weights)
+    out = reference_of(cell.config).train(cell.config, t["optimizer"], params, batches,
+                                          prec, row_weights)
     change = weights.change_norms(out.pop("params"), key, arch.num_layers)
     return {"losses": out["losses"], "grad": out["first_grad"], "change": change}
 
@@ -141,6 +147,7 @@ def run(cell, seed: int, seconds: float, spans, window_trace, process_start: flo
     window_trace.stop()
     if len(compiles.events) != first_compile:
         raise RuntimeError(f"{len(compiles.events) - first_compile} compiles in the window")
+    step_hlo = trainer.step_hlo() if window_trace.directory else None
 
     tokens_per_s = steps * trainer.tokens_per_step / window_s
     t = cell.traffic
@@ -154,7 +161,8 @@ def run(cell, seed: int, seconds: float, spans, window_trace, process_start: flo
     return Outcome(
         end_to_end={"train_tokens_per_s": tokens_per_s, "setup_s": setup_s},
         attempted=steps, failed=0, numbers=numbers, memory_peak_bytes=peak,
+        step_hlo=step_hlo,
         record={"steps": steps, "window_s": window_s, "tokens_per_s": tokens_per_s,
-                "spans": dict(spans.seconds), "span_counts": dict(spans.count),
+                "spans": dict(spans.seconds),
                 "flops_per_token": train_flops_per_token(cell.config, t["seq_len"]),
                 "chips": cell.chips, "peak_bytes": peak})

@@ -2,10 +2,23 @@
 
 Written from the published description of the architecture (pre-norm
 RMSNorm, rotary positions, grouped-query causal attention, a gated-SiLU
-MLP or a top-k mixture of gated-SiLU experts, an untied head) and from the
-configuration file's numbers; it imports nothing of the program under
-test. Leaves are addressed by the same paths the benchmark's weight
-generator uses.
+MLP or a top-k mixture of gated-SiLU experts) and from the configuration
+file's numbers; it imports nothing of the program under test. Leaves are
+addressed by the same paths the benchmark's weight generator uses.
+
+Granite's multipliers, as ``GraniteMoeForCausalLM`` of Hugging Face
+transformers applies them (``modeling_granitemoe.py``; the model card of
+ibm-granite/granite-3.0-3b-a800m-base), each applied only where the file
+gives a value other than the plain one, so that a plain model's
+arithmetic is exactly the llama decoder's:
+
+- ``x_0 = embedding_multiplier * embed[tokens]``;
+- attention scores ``q k^T * attention_multiplier`` (plain:
+  ``/ sqrt(head_dim)``);
+- each sublayer's output ``x + residual_multiplier * f(norm(x))``;
+- logits ``norm(x_L) W_head / logits_scaling``;
+- with ``tie_word_embeddings`` the head is the embedding transposed,
+  ``W_head = embed^T``.
 
 ``prec`` selects the matrix products: ``"f32"`` is float32 at the highest
 precision (the reference); ``"fp8"`` is the control, the precision below
@@ -17,9 +30,10 @@ under a per-tensor scale that maps its largest magnitude to the format's
 largest value; products accumulate in float32 and are scaled back.
 
 Departures, each a rule of the configuration as run and not of the
-published model: a mixture-of-experts layer keeps at most
-``capacity_factor * k * T / E`` assignments per expert, in token order,
-and drops the rest; weight decay applies to every stored array of rank 2
+published model: with a ``capacity_factor`` a mixture-of-experts layer
+keeps at most ``capacity_factor * k * T / E`` assignments per expert, in
+token order, and drops the rest (without one it keeps every assignment,
+as the published models route); weight decay applies to every stored array of rank 2
 or more, which in the layer-stacked layout includes the per-layer norm
 scales.
 """
@@ -84,21 +98,46 @@ def einsum(spec: str, a, b, prec: str):
 
 
 def dims(config: Dict) -> Dict:
+    """The sizes and constants of the equations; a multiplier is None
+    where the file gives the plain value or none."""
+    if config.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"the reference has no hidden_act {config['hidden_act']!r}")
     h = config["hidden_size"]
     nh = config["num_attention_heads"]
     hd = config.get("head_dim") or h // nh
-    for key, plain in (("embedding_multiplier", 1.0), ("residual_multiplier", 1.0),
-                       ("logits_scaling", 1.0), ("attention_multiplier", hd ** -0.5)):
-        if config.get(key, plain) != plain:
-            raise ValueError(f"the reference has no {key} other than {plain}")
+
+    def multiplier(key, plain):
+        value = config.get(key, plain)
+        return None if value == plain else value
+
     return dict(
-        hidden=h, heads=nh, kv_heads=config["num_key_value_heads"],
-        head_dim=config.get("head_dim") or h // nh,
+        hidden=h, heads=nh, kv_heads=config["num_key_value_heads"], head_dim=hd,
         layers=config["num_hidden_layers"], vocab=config["vocab_size"],
         eps=config["rms_norm_eps"], theta=config["rope_theta"],
         experts=config.get("num_local_experts", 0),
         top_k=config.get("num_experts_per_tok", 0),
-        capacity_factor=config.get("capacity_factor", 0.0))
+        capacity_factor=config.get("capacity_factor"),
+        embedding_multiplier=multiplier("embedding_multiplier", 1.0),
+        attention_multiplier=multiplier("attention_multiplier", hd ** -0.5),
+        residual_multiplier=multiplier("residual_multiplier", 1.0),
+        logits_scaling=multiplier("logits_scaling", 1.0),
+        tied=config.get("tie_word_embeddings", False))
+
+
+def scaled(x, multiplier):
+    """``x * multiplier``; ``x`` itself where the multiplier is plain."""
+    return x if multiplier is None else x * multiplier
+
+
+def head_weights(leaf, dm):
+    """The head ``[H, V]`` from ``leaf(path)``: the embedding transposed
+    where the model ties them."""
+    return leaf("['embed']").T if dm["tied"] else leaf("['lm_head']")
+
+
+def logits_of(x, head, dm, prec):
+    logits = einsum("...h,hv->...v", x, head, prec)
+    return logits if dm["logits_scaling"] is None else logits / dm["logits_scaling"]
 
 
 def rmsnorm(x, w, eps):
@@ -115,8 +154,9 @@ def rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def attention(q, k, v, prec):
-    """Causal grouped-query attention. q: [B,S,nh,d]; k, v: [B,S,nkv,d]."""
+def attention(q, k, v, prec, multiplier=None):
+    """Causal grouped-query attention. q: [B,S,nh,d]; k, v: [B,S,nkv,d].
+    Scores are scaled by ``multiplier``, or by 1/sqrt(d) where it is None."""
     B, S, nh, d = q.shape
     g = nh // k.shape[2]
     k = jnp.repeat(k, g, axis=2)
@@ -124,7 +164,8 @@ def attention(q, k, v, prec):
 
     @jax.checkpoint
     def block(q0, qb):
-        s = einsum("bqhd,bkhd->bhqk", qb, k, prec) / np.sqrt(d)
+        s = einsum("bqhd,bkhd->bhqk", qb, k, prec)
+        s = s / np.sqrt(d) if multiplier is None else s * multiplier
         qpos = q0 + jnp.arange(qb.shape[1])
         s = jnp.where(qpos[:, None] >= jnp.arange(S)[None, :], s, -jnp.inf)
         return einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, prec)
@@ -142,17 +183,20 @@ def mlp(x, p, prec):
 
 
 def moe(x, p, dm, prec):
-    """Top-k mixture of gated-SiLU experts with a capacity per expert.
+    """Top-k mixture of gated-SiLU experts, with a capacity per expert
+    where the configuration gives a ``capacity_factor``.
 
     x: [T, H]. Each expert is applied to every token and weighted by that
     token's kept gate for it (zero where it was not chosen or was
-    dropped)."""
+    dropped). Without a capacity factor the capacity is T, which no
+    expert can exceed: a token chooses an expert at most once."""
     T = x.shape[0]
     E, k = dm["experts"], dm["top_k"]
     probs = jax.nn.softmax(einsum("th,he->te", x, p["router"], prec), axis=-1)
     gates, chosen = lax.top_k(probs, k)
     gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
-    capacity = int(max(1, dm["capacity_factor"] * k * T / E))
+    cf = dm["capacity_factor"]
+    capacity = T if cf is None else int(max(1, cf * k * T / E))
     hit = chosen.reshape(-1)[:, None] == jnp.arange(E)[None, :]        # [T*k, E]
     rank = jnp.cumsum(hit, axis=0) - 1                # earlier picks of the expert
     kept = jnp.sum(jnp.where(hit, rank, 0), axis=-1) < capacity        # [T*k]
@@ -177,12 +221,13 @@ def block(x, lp, dm, prec):
     q = rope(einsum("bsh,hf->bsf", h, a["wq"], prec).reshape(B, S, nh, d), pos, dm["theta"])
     k = rope(einsum("bsh,hf->bsf", h, a["wk"], prec).reshape(B, S, nkv, d), pos, dm["theta"])
     v = einsum("bsh,hf->bsf", h, a["wv"], prec).reshape(B, S, nkv, d)
-    o = attention(q, k, v, prec).reshape(B, S, nh * d)
-    x = x + einsum("bsf,fh->bsh", o, a["wo"], prec)
+    o = attention(q, k, v, prec, dm["attention_multiplier"]).reshape(B, S, nh * d)
+    r = dm["residual_multiplier"]
+    x = x + scaled(einsum("bsf,fh->bsh", o, a["wo"], prec), r)
     h = rmsnorm(x, lp["norm2"], dm["eps"])
     if dm["experts"]:
-        return x + moe(h.reshape(B * S, H), lp["moe"], dm, prec).reshape(B, S, H)
-    return x + mlp(h, lp["mlp"], prec)
+        return x + scaled(moe(h.reshape(B * S, H), lp["moe"], dm, prec).reshape(B, S, H), r)
+    return x + scaled(mlp(h, lp["mlp"], prec), r)
 
 
 def nest(flat: Dict) -> Dict:
@@ -205,7 +250,7 @@ def layer_params(params: Dict, layer: int) -> Dict:
 
 def hidden(params: Dict, tokens, dm, prec):
     """Final-norm hidden states [B, S, H] of token ids [B, S]."""
-    x = params["['embed']"][tokens]
+    x = scaled(params["['embed']"][tokens], dm["embedding_multiplier"])
     for layer in range(dm["layers"]):
         x = jax.checkpoint(partial(block, dm=dm, prec=prec))(
             x, layer_params(params, layer))
@@ -220,9 +265,11 @@ def loss(params: Dict, tokens, labels, weights, dm, prec):
     n = max(1, x.shape[0] // LOSS_BLOCK)
     size = x.shape[0] // n
 
+    head = head_weights(params.__getitem__, dm)
+
     @jax.checkpoint
     def nll(xb, lb):
-        logits = einsum("th,hv->tv", xb, params["['lm_head']"], prec)
+        logits = logits_of(xb, head, dm, prec)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         return lse - jnp.take_along_axis(logits, lb[:, None], axis=-1)[:, 0]
 
@@ -304,13 +351,14 @@ def position_logits(config: Dict, make_layer, make_leaf, tokens: np.ndarray,
     device at a time, applied to ``rows`` sequences at a time."""
     dm = dims(config)
     embed = make_leaf("['embed']")
-    xs = [embed[jnp.asarray(tokens[i:i + rows])] for i in range(0, len(tokens), rows)]
+    xs = [scaled(embed[jnp.asarray(tokens[i:i + rows])], dm["embedding_multiplier"])
+          for i in range(0, len(tokens), rows)]
     del embed
     step = jax.jit(partial(block, dm=dm, prec=prec))
     for layer in range(dm["layers"]):
         w = nest(make_layer(layer))
         xs = [step(x, w) for x in xs]
-    norm, head = make_leaf("['final_norm']"), make_leaf("['lm_head']")
+    norm, head = make_leaf("['final_norm']"), head_weights(make_leaf, dm)
     for x in xs:
         for row in rmsnorm(x, norm, dm["eps"]):
-            yield einsum("sh,hv->sv", row, head, prec)
+            yield logits_of(row, head, dm, prec)

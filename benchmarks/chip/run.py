@@ -5,13 +5,16 @@ process finds, and prints one JSON line as the last line of its output.
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> \
         --seconds <run_seconds> --trace <0|1>
 
-The cell names a configuration file, a traffic file (whose ``job`` picks
-``jobs/<job>.py``) and a chip count; the limits of its compared numbers
-are in ``limits/<cell>.json``. With ``--trace 0`` the line carries the
-cell's end-to-end metrics; with ``--trace 1`` the first seconds of the
-window are profiled and the line carries the cell's per-layer metrics,
-each read by ``metrics/<metric>.py``. Off a TPU, or with fewer chips than
-the cell asks for, it exits non-zero and prints no result.
+The cell names a configuration file (which names its reference module),
+a traffic file (whose ``job`` picks ``jobs/<job>.py``) and a chip count;
+the limits of its compared numbers are in ``limits/<cell>.json``. With
+``--trace 0`` the line carries the cell's end-to-end metrics; with
+``--trace 1`` the first seconds of the window are profiled and the line
+carries the cell's per-layer metrics, each read by
+``metrics/<metric>.py`` from the run's record, and a ``breakdown``: the
+longest device ops, idle time by host span and device ms a step by named
+scope. Off a TPU, or with fewer chips than the cell asks for, it exits
+non-zero and prints no result.
 """
 
 from __future__ import annotations
@@ -47,14 +50,18 @@ def require_chips(count: int):
     return devices
 
 
-def enable_compile_cache() -> str:
+def enable_compile_cache(scoped: bool = False) -> str:
     """JAX's persistent cache at ``JAX_COMPILATION_CACHE_DIR`` when set, else
-    at the fixed ``<checkout>/.jax_cache``; every program is kept."""
+    at the fixed ``<checkout>/.jax_cache``; every program is kept. With
+    ``scoped`` a program's metadata is part of its key: the named scopes
+    are metadata, and a cached executable of the same program could
+    otherwise carry another tree's op names."""
     import jax
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(bench.ROOT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", scoped)
     return path
 
 
@@ -78,9 +85,33 @@ def cell_metrics(spec: dict, cell: str, trace: bool) -> list:
             if (cell in m["workloads"] if "workloads" in m else m["moves"] in moved)]
 
 
-def run_cell(spec: dict, cell_name: str, seed: int, seconds: float, trace: bool,
-             process_start: float, devices) -> dict:
-    """Runs the cell's job and returns the result line (a dict)."""
+def reduce_trace(profile, span_names, step_hlo) -> dict:
+    """``trace_reduce.reduce`` of a traced window, with ``scopes`` (device
+    ms a step by named scope; None without the step's HLO text)."""
+    import scopes
+    import trace_reduce
+    reduced = trace_reduce.reduce(profile, span_names)
+    if reduced is not None:
+        reduced["scopes"] = scopes.reduce(profile, step_hlo) if step_hlo else None
+    return reduced
+
+
+def breakdown(reduced: dict) -> dict:
+    """The traced window's longest device ops and its idle time by host
+    span; device ms a step by scope, which add up to ``step_busy_ms``, the
+    busy time of one of the ``scope_steps`` step executions they average."""
+    out = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"]}
+    by_scope = reduced["scopes"]
+    if by_scope is not None:
+        out.update(scopes=by_scope["ms"], step_busy_ms=by_scope["busy_ms"],
+                   scope_steps=by_scope["steps"])
+    return out
+
+
+def run_job(spec: dict, cell_name: str, seed: int, seconds: float, trace: bool,
+            process_start: float, devices):
+    """Runs the cell's job; returns the cell, the job's ``Outcome`` and the
+    record its per-layer readers read."""
     cell = bench.load_cell(cell_name, spec)
     job = importlib.import_module(f"jobs.{cell.traffic['job']}")
     spans = bench.Spans(trace=trace)
@@ -89,18 +120,23 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float, trace: bool,
         with bench.CompileLog() as compiles:
             out = job.run(cell, seed, seconds, spans, bench.WindowTrace(trace_dir),
                           process_start, compiles)
-        record = dict(out.record)
-        reduced = None
+        record = dict(out.record, config=cell.config, traffic=cell.traffic, trace=None)
         if trace:
             import trace_reduce
             profile = trace_reduce.load(trace_reduce.find_xplane(trace_dir))
-            reduced = trace_reduce.reduce(profile, spans.seconds.keys())
-        record["trace"] = reduced
+            record["trace"] = reduce_trace(profile, spans.seconds.keys(), out.step_hlo)
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
     record["peaks"] = bench.load_peaks(devices[0].device_kind)
+    return cell, out, record
 
+
+def run_cell(spec: dict, cell_name: str, seed: int, seconds: float, trace: bool,
+             process_start: float, devices) -> dict:
+    """Runs the cell's job and returns the result line (a dict)."""
+    cell, out, record = run_job(spec, cell_name, seed, seconds, trace, process_start,
+                                devices)
     metrics = {}
     for m in cell_metrics(spec, cell_name, trace):
         value = out.end_to_end.get(m["name"]) if not trace else reader(m["name"])(record)
@@ -111,11 +147,11 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float, trace: bool,
             "metrics": metrics,
             "device": {"platform": devices[0].platform, "kind": devices[0].device_kind,
                        "count": len(devices), "memory_peak_bytes": out.memory_peak_bytes}}
-    if trace and reduced is not None:
+    reduced = record["trace"]
+    if reduced is not None:
         line["device"]["busy_s"] = reduced["busy_s"]
         line["device"]["window_s"] = reduced["window_s"]
-        line["breakdown"] = {"device_ops": reduced["device_ops"],
-                             "idle_gaps": reduced["idle_gaps"]}
+        line["breakdown"] = breakdown(reduced)
     line["checks"] = checks
     return line
 
@@ -133,7 +169,7 @@ def main(argv=None) -> int:
     if args.workload not in chips:
         raise SystemExit(f"run.py: no workload {args.workload!r}; no result")
     devices = require_chips(chips[args.workload])
-    enable_compile_cache()
+    enable_compile_cache(bool(args.trace))
     line = run_cell(spec, args.workload, args.seed, args.seconds, bool(args.trace),
                     PROCESS_START, devices)
     for name, check in line["checks"].items():

@@ -173,3 +173,12 @@ def reduce(profile, hlo_text: str) -> Optional[Dict]:
     return {"program": step, "steps": executions / len(planes), "busy_ms": busy * per_step,
             "unmatched_ms": unmatched * per_step, "ms": {label: ms[label] for label in order},
             "top_ops": {label: top[label] for label in order if top[label]}}
+
+
+def scope_ms(record, *names: str) -> Optional[float]:
+    """Device ms a step in the named scopes, from a run record's traced
+    window; None where the record has no scopes or the step none of them."""
+    reduced = (record.get("trace") or {}).get("scopes")
+    if reduced is None or not set(names) & set(reduced["ms"]):
+        return None
+    return sum(reduced["ms"].get(n, 0.0) for n in names)

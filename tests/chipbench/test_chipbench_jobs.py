@@ -119,8 +119,9 @@ def faulty_train_step(monkeypatch, fault):
 @pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
 @pytest.mark.parametrize("name", TRAIN)
 def test_train_fault_is_not_correct(monkeypatch, name, fault):
+    cell = tiny_cell(name)           # limits from sound readings, before the fault
     faulty_train_step(monkeypatch, fault)
-    out, correct = run_job(tiny_cell(name))
+    out, correct = run_job(cell)
     assert not correct, out.numbers
 
 
@@ -152,8 +153,9 @@ def faulty_serve_step(monkeypatch, fault):
 @pytest.mark.parametrize("fault", ["unchanged", "token_altered", "half_batch"])
 @pytest.mark.parametrize("name", DECODE)
 def test_decode_fault_is_not_correct(monkeypatch, name, fault):
+    cell = tiny_cell(name)           # limits from sound readings, before the fault
     faulty_serve_step(monkeypatch, fault)
-    out, correct = run_job(tiny_cell(name))
+    out, correct = run_job(cell)
     assert not correct, out.numbers
 
 

@@ -12,6 +12,7 @@ import pytest
 
 from chipbench_paths import BENCH_DIR
 
+import run
 import scopes
 import trace_reduce as tr
 
@@ -168,6 +169,58 @@ def test_recorded_step_busy_time_is_inside_the_window(scoped):
     profile, got = scoped
     whole = tr.reduce(profile, ["dispatch", "loss_fetch"])
     assert got["busy_ms"] * got["steps"] <= whole["busy_s"] * 1e3 * (1 + 1e-9)
+
+
+@pytest.fixture(scope="module")
+def run_reduced():
+    profile = tr.load(str(SCOPED.with_suffix(".xplane.pb")))
+    return run.reduce_trace(profile, ["dispatch", "loss_fetch"],
+                            SCOPED.with_suffix(".hlo.txt").read_text())
+
+
+def test_breakdown_scopes_add_up_to_the_step_busy_time(run_reduced):
+    got = run.breakdown(run_reduced)
+    assert list(got) == ["device_ops", "idle_gaps", "scopes", "step_busy_ms", "scope_steps"]
+    ms = got["scopes"]
+    assert list(ms) == sorted(ms, key=lambda k: -ms[k])
+    stepped = sum(v for k, v in ms.items() if k != scopes.OTHER)
+    assert stepped == pytest.approx(got["step_busy_ms"], rel=1e-6)
+    assert got["step_busy_ms"] * got["scope_steps"] <= run_reduced["busy_s"] * 1e3 * (1 + 1e-9)
+
+
+def test_no_hlo_text_no_scopes():
+    profile = tr.load(str(SCOPED.with_suffix(".xplane.pb")))
+    reduced = run.reduce_trace(profile, ["dispatch", "loss_fetch"], None)
+    assert reduced["scopes"] is None
+    assert list(run.breakdown(reduced)) == ["device_ops", "idle_gaps"]
+
+
+SCOPE_READERS = {
+    "attention_ms.train": ["attention"], "mlp_ms.train": ["mlp"],
+    "head_loss_ms.train": ["head", "loss"], "optimizer_ms.train": ["optimizer"],
+    "unscoped_ms.train": [scopes.UNSCOPED], "attention_ms.decode": ["attention"],
+    "mlp_ms.decode": ["mlp"], "layer_scan_ms.decode": ["layer_scan"],
+    "unscoped_ms.decode": [scopes.UNSCOPED],
+}
+
+
+def reader(name):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(name, BENCH_DIR / "metrics" / f"{name}.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
+
+
+@pytest.mark.parametrize("name", SCOPE_READERS)
+def test_scope_readers_read_only_scopes(name, run_reduced):
+    read = reader(name)
+    want = sum(run_reduced["scopes"]["ms"][s] for s in SCOPE_READERS[name])
+    assert want > 0
+    assert read({"trace": run_reduced}) == pytest.approx(want, rel=1e-12)
+    assert read({"trace": None}) is None
+    assert read({"trace": dict(run_reduced, scopes=None)}) is None
+    assert read({"trace": dict(run_reduced, scopes={"ms": {"embed": 1.0}})}) is None
 
 
 def test_reduce_keeps_its_readings_on_the_matmul_trace():

@@ -106,6 +106,14 @@ def test_configs_files_and_cuts():
             assert cfg[key] != published[key]
 
 
+def test_configs_map_to_the_program_and_name_their_reference():
+    for c in SPEC["configs"]:
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        bench.arch_of(cfg)
+        reference = bench.reference_of(cfg)
+        assert callable(reference.train) and callable(reference.position_logits)
+
+
 def test_cells_find_their_files():
     for w in SPEC["workloads"]:
         cell = bench.load_cell(w["name"], SPEC)
@@ -143,29 +151,58 @@ def test_harness_fails_with_only_its_own_files(tmp_path):
     assert "{" not in p.stdout
 
 
+TINY_REFERENCE = '''"""A reference module that only this copy has: the llama
+reference, saying when it trains."""
+import sys
+
+from reference.llama import position_logits, train as llama_train
+
+
+def train(*args, **kwargs):
+    print("tiny reference trains", file=sys.stderr)
+    return llama_train(*args, **kwargs)
+'''
+
+
 def test_a_new_cell_needs_only_new_files(tmp_path):
-    """A copy of the benchmark gains a configuration, a traffic mix and a
-    cell by new files and new entries only; the harness finds and runs it
-    (its job at a tiny size on the CPU, the chip check skipped)."""
+    """A copy of the benchmark gains configurations, traffic mixes and
+    cells by new files and new entries only, one of them a configuration
+    that names a reference module of its own; the harness finds and runs
+    them (their jobs at a tiny size on the CPU, the chip check skipped)."""
     bench_copy = tmp_path / "benchmarks" / "chip"
     shutil.copytree(BENCH_DIR, bench_copy)
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    cfg = json.loads((BENCH_DIR / "configs" / "yi-6b.json").read_text())
-    cfg.update(name="tiny-dense", hidden_size=64, intermediate_size=128,
-               num_attention_heads=4, num_key_value_heads=2, num_hidden_layers=1,
-               vocab_size=128)
-    (bench_copy / "configs" / "tiny-dense.json").write_text(json.dumps(cfg))
-    (bench_copy / "traffic" / "decode.tiny.json").write_text(json.dumps(
-        {"job": "decode", "batch": 4, "context": 8, "turn_tokens": 4}))
-    (bench_copy / "limits" / "tiny.decode.json").write_text(json.dumps({"served_mean_gap": 0.1}))
-    spec["configs"].append({"name": "tiny-dense", "source": cfg["source"],
-                            "file": "benchmarks/chip/configs/tiny-dense.json",
-                            "reduced": [], "why": "a test"})
-    spec["workloads"].append({"name": "tiny.decode", "config": "tiny-dense",
-                              "traffic": "decode.tiny", "chips": 1, "why": "a test"})
-    for m in spec["end_to_end"] + spec["per_layer"]:
-        if "yi-6b.decode.b16.c1k" in m.get("workloads", []):
-            m["workloads"].append("tiny.decode")
+    tiny = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
+                num_key_value_heads=2, num_hidden_layers=1, vocab_size=128)
+    decode_cfg = dict(json.loads((BENCH_DIR / "configs" / "yi-6b.json").read_text()),
+                      name="tiny-dense", **tiny)
+    train_cfg = dict(json.loads((BENCH_DIR / "configs" / "yi-6b-train-stage.json").read_text()),
+                     name="tiny-train", reference="reference/tiny.py", **tiny)
+    (bench_copy / "reference" / "tiny.py").write_text(TINY_REFERENCE)
+    optimizer = bench.traffic.load("train.s4k.b1")["optimizer"]
+    files = {
+        "configs/tiny-dense.json": decode_cfg,
+        "configs/tiny-train.json": train_cfg,
+        "traffic/decode.tiny.json": {"job": "decode", "batch": 4, "context": 8,
+                                     "turn_tokens": 4},
+        "traffic/train.tiny.json": {"job": "train", "seq_len": 32, "global_batch": 2,
+                                    "microbatches": 1, "optimizer": optimizer},
+        "limits/tiny.decode.json": {"served_mean_gap": 0.1},
+        "limits/tiny.train.json": {"loss_gap": 0.05, "grad_gap": 0.05, "grad_diff": 0.5,
+                                   "change_gap": 0.5},
+    }
+    for path, content in files.items():
+        (bench_copy / path).write_text(json.dumps(content))
+    for cfg, job, like in ((decode_cfg, "decode", "yi-6b.decode.b16.c1k"),
+                           (train_cfg, "train", "yi-6b.train.s4k")):
+        spec["configs"].append({"name": cfg["name"], "source": cfg["source"],
+                                "file": f"benchmarks/chip/configs/{cfg['name']}.json",
+                                "reduced": [], "why": "a test"})
+        spec["workloads"].append({"name": f"tiny.{job}", "config": cfg["name"],
+                                  "traffic": f"{job}.tiny", "chips": 1, "why": "a test"})
+        for m in spec["end_to_end"] + spec["per_layer"]:
+            if like in m.get("workloads", []):
+                m["workloads"].append(f"tiny.{job}")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
     script = textwrap.dedent(f"""
         import json, sys, time, types
@@ -173,13 +210,19 @@ def test_a_new_cell_needs_only_new_files(tmp_path):
         import run
         spec = json.load(open({str(tmp_path / 'BENCHMARK.json')!r}))
         dev = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
-        line = run.run_cell(spec, "tiny.decode", 5, 0.5, False, time.perf_counter(), [dev])
-        print(json.dumps(line))
+        for cell in ("tiny.decode", "tiny.train"):
+            line = run.run_cell(spec, cell, 5, 0.5, False, time.perf_counter(), [dev])
+            print(json.dumps(line))
     """)
     p = subprocess.run([sys.executable, "-c", script], cwd=tmp_path, env=harness_env(),
-                       capture_output=True, text=True, timeout=300)
+                       capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
-    line = json.loads(p.stdout.strip().splitlines()[-1])
-    assert set(line["metrics"]) == {"decode_tokens_per_s", "decode_step_p95_ms", "setup_s"}
-    assert line["correct"] is True
-    assert list(line)[-1] == "checks"
+    decode_line, train_line = [json.loads(x) for x in p.stdout.strip().splitlines()[-2:]]
+    assert set(decode_line["metrics"]) == {"decode_tokens_per_s", "decode_step_p95_ms",
+                                           "setup_s"}
+    assert set(train_line["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    for line in (decode_line, train_line):
+        assert line["correct"] is True, line["checks"]
+        assert list(line)[-1] == "checks"
+    assert set(train_line["checks"]) == {"loss_gap", "grad_gap", "grad_diff", "change_gap"}
+    assert "tiny reference trains" in p.stderr
