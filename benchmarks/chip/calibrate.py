@@ -13,10 +13,20 @@ against the reference computed in fp8 (``prec="fp8"`` of the reference
 module that the configuration names) put in the program's place, and
 those of faults planted in the reference: half of the batch left out of
 the loss (training), or one served token of each session altered and half
-of the sessions left undecoded (decode). Training lines also name the leaf
-that sets ``grad_diff``. Each line is one JSON object. With
-``--write-limits`` it then sets each number's limit from these readings
-and writes ``limits/<cell>.json`` (see ``limits_of``). Needs a TPU.
+of the sessions left undecoded (decode). With experts the fp8 reference
+routes on its own, and its readings are taken both as the job would take
+them from a program that reports those routes (the float32 reference held
+to them, ``route_flip`` its own count) and against the float32 reference
+routing on its own (``route_flip`` the share of the fp8 choices that the
+float32 reference did not make). The one that stands where the program
+stands counts as ``control``: held where the step reports its routes,
+free where it does not; the other is printed as ``control_held`` or
+``control_free`` and not used for limits. A routing fault holds one slot
+of each token to an expert that the float32 reference did not choose.
+Training lines also name the leaf that sets ``grad_diff``. Each line is
+one JSON object. With ``--write-limits`` it then sets each number's limit
+from these readings and writes ``limits/<cell>.json`` (see ``limits_of``).
+Needs a TPU.
 """
 
 from __future__ import annotations
@@ -47,19 +57,58 @@ def half_rows(traffic_: dict) -> np.ndarray:
     return w
 
 
+# Kinds printed for the record and not used for limits.
+INFORMATIONAL = ("control_free", "control_held")
+
+
 def train_readings(cell, seed: int, control: bool):
     from jobs import train
     trainer = train.Trainer(cell, seed, bench.Spans())
     checked = trainer.first_steps()
     trainer.close()
     del trainer
-    ref = train.reference(cell, seed)
-    yield "program", train.compare(checked, ref), worst_leaf(checked, ref)
-    if control:
-        ctrl = train.reference(cell, seed, prec="fp8")
-        yield "control", train.compare(ctrl, ref), worst_leaf(ctrl, ref)
-        half = train.reference(cell, seed, row_weights=half_rows(cell.traffic))
-        yield "half_batch", train.compare(half, ref), worst_leaf(half, ref)
+    numbers, ref = train.check(cell, seed, checked)
+    yield "program", numbers, worst_leaf(checked, ref)
+    if not control:
+        return
+    if "route_flip" in numbers:            # ref was held to the program's routes
+        ref = train.reference(cell, seed)
+    ctrl = train.reference(cell, seed, prec="fp8")
+    free = train.compare(ctrl, ref)
+    if cell.config.get("num_local_experts"):
+        free["route_flip"] = max(map(not_chosen, ctrl["routes"], ref["routes"]))
+        held, held_ref = train.check(cell, seed, ctrl)
+        kinds = ("control", "control_free") if "route_flip" in numbers else \
+            ("control_held", "control")
+        yield kinds[0], held, worst_leaf(ctrl, held_ref)
+        yield kinds[1], free, worst_leaf(ctrl, ref)
+        wrong = [wrong_slot(r, cell.config["num_local_experts"], seed) for r in ref["routes"]]
+        fault = train.reference(cell, seed, routes=wrong)
+        numbers = dict(train.compare(fault, ref), route_flip=max(fault["route_flip"]))
+        yield "route_fault", numbers, worst_leaf(fault, ref)
+    else:
+        yield "control", free, worst_leaf(ctrl, ref)
+    half = train.reference(cell, seed, row_weights=half_rows(cell.traffic))
+    yield "half_batch", train.compare(half, ref), worst_leaf(half, ref)
+
+
+def not_chosen(routes: np.ndarray, own: np.ndarray) -> float:
+    """The share of the (token, slot) choices of ``routes`` that are not
+    among ``own``'s choices at that token; both ``[..., T, k]``."""
+    return float(np.mean(np.all(routes[..., :, None] != own[..., None, :], axis=-1)))
+
+
+def wrong_slot(routes: np.ndarray, experts: int, seed: int) -> np.ndarray:
+    """``routes`` with the last slot of each token's choices replaced by a
+    seeded expert that the token did not choose."""
+    rng = np.random.default_rng([seed, 11])
+    pick = (routes[..., -1] + rng.integers(1, experts, size=routes.shape[:-1])) % experts
+    for _ in range(routes.shape[-1]):
+        taken = np.any(routes == pick[..., None], axis=-1)
+        pick = np.where(taken, (pick + 1) % experts, pick)
+    out = routes.copy()
+    out[..., -1] = pick
+    return out
 
 
 def worst_leaf(got: dict, ref: dict) -> dict:
@@ -108,20 +157,25 @@ def limits_of(readings: list, job: str) -> dict:
     that is at least three times the lower; in training also the smallest
     of each fault that reads ten times the lower or more, and 1 for the
     gradient and change gaps, which a state left unchanged reads, where
-    that is three times the lower. The limit lies 60% of the way from the
-    lower to the upper. A number with no upper gets no limit."""
+    that is three times the lower. A reading of 0 separates nothing and
+    sets no upper. The limit lies 60% of the way from the lower to the
+    upper. A number with no upper gets no limit. ``route_flip`` is held
+    to this rule like the other numbers, where the program reads it."""
     out = {}
     for name in readings[0]["numbers"]:
         by_kind = {}
         for r in readings:
-            by_kind.setdefault(r["kind"], []).append(r["numbers"][name])
+            if name in r["numbers"] and r["kind"] not in INFORMATIONAL:
+                by_kind.setdefault(r["kind"], []).append(r["numbers"][name])
         lower = max(by_kind["program"])
         uppers = []
-        if min(by_kind.get("control", [0])) >= 3 * lower:
-            uppers.append(min(by_kind["control"]))
+        control = min(by_kind.get("control", [0]))
+        if control >= 3 * lower and control > 0:
+            uppers.append(control)
         if job == "train":
             for kind, values in by_kind.items():
-                if kind not in ("program", "control") and min(values) >= 10 * lower:
+                if kind not in ("program", "control") and min(values) >= 10 * lower \
+                        and min(values) > 0:
                     uppers.append(min(values))
             if name in ("grad_gap", "grad_diff", "change_gap") and 1.0 >= 3 * lower:
                 uppers.append(1.0)

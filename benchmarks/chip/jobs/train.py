@@ -9,6 +9,15 @@ moment after one step, copied to the host, over ``1 - b1``) and each
 leaf's change after the three. The window then drives the same ``Trainer`` on. Once the window has
 closed and the program's state is freed, the float32 reference repeats the
 three steps from the same seed.
+
+Where the step's metrics carry ``moe_routes`` (int32 ``[num_layers,
+tokens, top_k]``, the expert ids the step chose for its one microbatch's
+tokens in row-major order), the checked steps keep them and the reference
+is held to them: a near tie that the program breaks the other way then
+moves no gradient, and ``route_flip``, the share of the program's choices
+that are not among the reference's own top-k (worst step), joins the
+compared numbers. Routes that do not fit the batch or the experts, or
+that name an expert twice for one token, read ``route_flip`` 1.
 """
 
 from __future__ import annotations
@@ -53,23 +62,33 @@ class Trainer:
         self.tokens_per_step = t["seq_len"] * t["global_batch"]
         self.spans = spans
 
-    def step(self) -> float:
+    def step(self, routes: Optional[list] = None) -> float:
+        """One step, and its loss once the host holds it; appends the
+        step's ``moe_routes`` to ``routes`` where given and reported."""
         with self.spans("data_wait"):
             batch = next(self.data)
         with self.spans("dispatch"):
             self.params, self.opt_state, m = self.fn(self.params, self.opt_state, batch)
         with self.spans("loss_fetch"):
-            return float(m["loss"])
+            loss = float(m["loss"])
+        if routes is not None and "moe_routes" in m:
+            routes.append(np.asarray(jax.device_get(m["moe_routes"])))
+        return loss
 
     def first_steps(self) -> Dict:
-        """The checked steps, through the window's own call and feed."""
-        losses = [self.step()]
+        """The checked steps, through the window's own call and feed, with
+        the expert ids each chose where the step reports them."""
+        routes = []
+        losses = [self.step(routes)]
         moment = jax.device_get(weights.flatten(self.opt_state["m"]))
-        losses += [self.step() for _ in range(CHECKED_STEPS - 1)]
+        losses += [self.step(routes) for _ in range(CHECKED_STEPS - 1)]
         change = weights.change_norms(weights.flatten(self.params), self.key,
                                       self.arch.num_layers)
-        return {"losses": losses, "grad": moment, "grad_scale": 1 / (1 - self.b1),
-                "change": change}
+        out = {"losses": losses, "grad": moment, "grad_scale": 1 / (1 - self.b1),
+               "change": change}
+        if routes:
+            out["routes"] = routes
+        return out
 
     def step_hlo(self) -> str:
         """The compiled step's HLO text, whose op names the trace's ops carry."""
@@ -83,9 +102,11 @@ class Trainer:
 
 
 def reference(cell, seed: int, prec: str = "f32",
-              row_weights: Optional[np.ndarray] = None) -> Dict:
+              row_weights: Optional[np.ndarray] = None, routes=None) -> Dict:
     """The readings of the checked steps from ``seed`` of the reference
-    that the configuration names."""
+    that the configuration names; with ``routes`` (one ``[L, T, k]`` array
+    a step) its experts are held to them, and the readings also have its
+    own choices (``routes``) and ``route_flip`` a step."""
     arch = arch_of(cell.config)
     t = cell.traffic
     key = weights.seed_key(seed)
@@ -94,9 +115,43 @@ def reference(cell, seed: int, prec: str = "f32",
                                   t["global_batch"], t["microbatches"])
                for s in range(CHECKED_STEPS)]
     out = reference_of(cell.config).train(cell.config, t["optimizer"], params, batches,
-                                          prec, row_weights)
+                                          prec, row_weights, routes)
     change = weights.change_norms(out.pop("params"), key, arch.num_layers)
-    return {"losses": out["losses"], "grad": out["first_grad"], "change": change}
+    got = {"losses": out["losses"], "grad": out["first_grad"], "change": change}
+    got.update({k: out[k] for k in ("routes", "route_flip") if k in out})
+    return got
+
+
+def route_shape(cell) -> tuple:
+    """``[num_layers, tokens, top_k]`` of one step's expert ids."""
+    c, t = cell.config, cell.traffic
+    return (c["num_hidden_layers"], t["seq_len"] * t["global_batch"] // t["microbatches"],
+            c["num_experts_per_tok"])
+
+
+def routes_fit(routes, cell) -> bool:
+    """Every checked step's ids have the step's shape, name an expert, and
+    name ``top_k`` different experts for each token."""
+    experts = cell.config.get("num_local_experts", 0)
+    return len(routes) == CHECKED_STEPS and all(
+        r.shape == route_shape(cell) and np.issubdtype(r.dtype, np.integer)
+        and r.min() >= 0 and r.max() < experts
+        and np.all(np.diff(np.sort(r, axis=-1), axis=-1) != 0) for r in routes)
+
+
+def check(cell, seed: int, checked: Dict) -> tuple:
+    """The compared numbers of the checked steps against the reference
+    from ``seed``, and the reference's readings. Where the program reported
+    its expert ids the reference is held to them and ``route_flip`` joins;
+    ids that do not fit (``routes_fit``) read 1 and the reference routes
+    freely."""
+    routes = checked.get("routes")
+    held = routes is not None and routes_fit(routes, cell)
+    ref = reference(cell, seed, routes=routes if held else None)
+    numbers = compare(checked, ref)
+    if routes is not None:
+        numbers["route_flip"] = max(ref["route_flip"]) if held else 1.0
+    return numbers, ref
 
 
 def compare(program: Dict, ref: Dict) -> Dict[str, float]:
@@ -157,7 +212,7 @@ def run(cell, seed: int, seconds: float, spans, window_trace, process_start: flo
     del trainer
     gc.collect()
 
-    numbers = compare(checked, reference(cell, seed))
+    numbers, _ = check(cell, seed, checked)
     return Outcome(
         end_to_end={"train_tokens_per_s": tokens_per_s, "setup_s": setup_s},
         attempted=steps, failed=0, numbers=numbers, memory_peak_bytes=peak,

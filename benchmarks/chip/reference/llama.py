@@ -36,6 +36,18 @@ token order, and drops the rest (without one it keeps every assignment,
 as the published models route); weight decay applies to every stored array of rank 2
 or more, which in the layer-stacked layout includes the per-layer norm
 scales.
+
+Expert routing: ``moe``, ``block``, ``hidden``, ``loss`` and ``train``
+take optional ``routes``, each layer's expert ids ``[T, k]`` in the
+batch's row-major token order; an id below 0, and every slot where none
+are given, takes the reference's own top-k choice. The layer uses those
+ids, with gates from its own probabilities at them, renormalised, and
+capacity drops counted from them in token order. It reports its own
+top-k choices and ``route_flip``, the share of the (token, slot) choices
+used that are not among its own top-k at that token (0 where it routes
+on its own). So a comparison with a program that routes in another
+precision judges the arithmetic at the program's choices, and the
+choices apart.
 """
 
 from __future__ import annotations
@@ -182,18 +194,23 @@ def mlp(x, p, prec):
     return einsum("...f,fh->...h", jax.nn.silu(gate) * up, p["wo"], prec)
 
 
-def moe(x, p, dm, prec):
+def moe(x, p, dm, prec, routes=None):
     """Top-k mixture of gated-SiLU experts, with a capacity per expert
     where the configuration gives a ``capacity_factor``.
 
-    x: [T, H]. Each expert is applied to every token and weighted by that
-    token's kept gate for it (zero where it was not chosen or was
-    dropped). Without a capacity factor the capacity is T, which no
-    expert can exceed: a token chooses an expert at most once."""
+    x: [T, H]; routes: [T, k] expert ids, below 0 for its own choice.
+    Each expert is applied to every token and weighted by that token's
+    kept gate for it (zero where it was not chosen or was dropped).
+    Without a capacity factor the capacity is T, which no expert can
+    exceed where a token chooses an expert at most once. Returns ``(out,
+    own, flip)``: its own top-k ids, and the share of the choices used
+    that are not among them."""
     T = x.shape[0]
     E, k = dm["experts"], dm["top_k"]
     probs = jax.nn.softmax(einsum("th,he->te", x, p["router"], prec), axis=-1)
-    gates, chosen = lax.top_k(probs, k)
+    own = lax.top_k(probs, k)[1]
+    chosen = own if routes is None else jnp.where(routes < 0, own, routes)
+    gates = jnp.take_along_axis(probs, chosen, axis=-1)
     gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     cf = dm["capacity_factor"]
     capacity = T if cf is None else int(max(1, cf * k * T / E))
@@ -209,10 +226,14 @@ def moe(x, p, dm, prec):
         return acc + combine[:, e, None] * mlp(x, pe, prec), None
 
     out, _ = lax.scan(expert, jnp.zeros_like(x), jnp.arange(E))
-    return out
+    flip = jnp.mean(jnp.all(chosen[:, :, None] != own[:, None, :], axis=-1))
+    return out, own, flip
 
 
-def block(x, lp, dm, prec):
+def block(x, lp, dm, prec, routes=None):
+    """One decoder layer: ``(x, own, flip)``, the expert layer's own
+    choices and ``route_flip`` as ``moe`` gives them (None without
+    experts), with ``routes`` its ``[B*S, k]`` ids."""
     B, S, H = x.shape
     nh, nkv, d = dm["heads"], dm["kv_heads"], dm["head_dim"]
     pos = jnp.arange(S)
@@ -226,8 +247,9 @@ def block(x, lp, dm, prec):
     x = x + scaled(einsum("bsf,fh->bsh", o, a["wo"], prec), r)
     h = rmsnorm(x, lp["norm2"], dm["eps"])
     if dm["experts"]:
-        return x + scaled(moe(h.reshape(B * S, H), lp["moe"], dm, prec).reshape(B, S, H), r)
-    return x + scaled(mlp(h, lp["mlp"], prec), r)
+        out, own, flip = moe(h.reshape(B * S, H), lp["moe"], dm, prec, routes)
+        return x + scaled(out.reshape(B, S, H), r), own, flip
+    return x + scaled(mlp(h, lp["mlp"], prec), r), None, None
 
 
 def nest(flat: Dict) -> Dict:
@@ -248,18 +270,27 @@ def layer_params(params: Dict, layer: int) -> Dict:
                  if p.startswith("['layers']")})
 
 
-def hidden(params: Dict, tokens, dm, prec):
-    """Final-norm hidden states [B, S, H] of token ids [B, S]."""
+def hidden(params: Dict, tokens, dm, prec, routes=None):
+    """Final-norm hidden states [B, S, H] of token ids [B, S], each
+    expert layer's own choices ``[L, B*S, k]`` and ``route_flip`` over
+    all layers (both None without experts); ``routes``: ``[L, B*S, k]``."""
     x = scaled(params["['embed']"][tokens], dm["embedding_multiplier"])
+    own, flips = [], []
     for layer in range(dm["layers"]):
-        x = jax.checkpoint(partial(block, dm=dm, prec=prec))(
-            x, layer_params(params, layer))
-    return rmsnorm(x, params["['final_norm']"], dm["eps"])
+        x, o, f = jax.checkpoint(lambda x, lp, r: block(x, lp, dm, prec, r))(
+            x, layer_params(params, layer), None if routes is None else routes[layer])
+        own.append(o)
+        flips.append(f)
+    x = rmsnorm(x, params["['final_norm']"], dm["eps"])
+    if not dm["experts"]:
+        return x, None, None
+    return x, jnp.stack(own), jnp.mean(jnp.stack(flips))
 
 
-def loss(params: Dict, tokens, labels, weights, dm, prec):
-    """Mean next-token cross entropy over the tokens with weight 1."""
-    x = hidden(params, tokens, dm, prec)
+def loss(params: Dict, tokens, labels, weights, dm, prec, routes=None):
+    """Mean next-token cross entropy over the tokens with weight 1, and
+    ``(own choices, route_flip)`` as ``hidden`` gives them."""
+    x, own, flip = hidden(params, tokens, dm, prec, routes)
     H = x.shape[-1]
     x, labels, weights = x.reshape(-1, H), labels.reshape(-1), weights.reshape(-1)
     n = max(1, x.shape[0] // LOSS_BLOCK)
@@ -275,7 +306,7 @@ def loss(params: Dict, tokens, labels, weights, dm, prec):
 
     total = sum(jnp.sum(nll(x[i * size:(i + 1) * size], labels[i * size:(i + 1) * size])
                         * weights[i * size:(i + 1) * size]) for i in range(n))
-    return total / jnp.sum(weights)
+    return total / jnp.sum(weights), (own, flip)
 
 
 # ---------------------------------------------------------------------------
@@ -304,18 +335,24 @@ def _adam_leaf(p, g, m, v, lr, scale, c1, c2, b1, b2, eps, wd, decay):
 
 
 def train(config: Dict, opt: Dict, params: Dict, batches, prec: str = "f32",
-          row_weights: Optional[np.ndarray] = None) -> Dict:
+          row_weights: Optional[np.ndarray] = None, routes=None) -> Dict:
     """Runs ``len(batches)`` AdamW steps from ``params`` (flat, float32,
     on the device; consumed). Returns the loss of each step, the first
-    (clipped) gradient as host arrays, and the final params."""
+    (clipped) gradient as host arrays, and the final params; with
+    experts also each step's own choices (``routes``) and ``route_flip``.
+    ``routes``: one ``[L, T, k]`` array of expert ids a step."""
     dm = dims(config)
-    vg = jax.jit(jax.value_and_grad(partial(loss, dm=dm, prec=prec)))
+    vg = jax.jit(jax.value_and_grad(partial(loss, dm=dm, prec=prec), has_aux=True))
     moments: Dict[str, tuple] = {}
-    losses, first_grad = [], None
+    losses, first_grad, own, flips = [], None, [], []
     for t, batch in enumerate(batches, start=1):
         tokens, labels = batch["tokens"][0], batch["labels"][0]
         w = np.ones(tokens.shape, np.float32) if row_weights is None else row_weights
-        value, grads = vg(params, tokens, labels, w)
+        held = None if routes is None else jnp.asarray(routes[t - 1])
+        (value, (o, flip)), grads = vg(params, tokens, labels, w, routes=held)
+        if dm["experts"]:
+            own.append(np.asarray(o))
+            flips.append(float(flip))
         losses.append(float(value))
         gnorm = float(jnp.sqrt(sum(jnp.sum(g * g) for g in grads.values())))
         scale = min(1.0, opt["grad_clip"] / (gnorm + 1e-9)) if opt["grad_clip"] > 0 else 1.0
@@ -335,7 +372,10 @@ def train(config: Dict, opt: Dict, params: Dict, batches, prec: str = "f32",
             params[path] = p
             moments[path] = None if last else (np.asarray(m), np.asarray(v))
             del m, v
-    return {"losses": losses, "first_grad": first_grad, "params": params}
+    out = {"losses": losses, "first_grad": first_grad, "params": params}
+    if dm["experts"]:
+        out.update(routes=own, route_flip=flips)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -354,7 +394,7 @@ def position_logits(config: Dict, make_layer, make_leaf, tokens: np.ndarray,
     xs = [scaled(embed[jnp.asarray(tokens[i:i + rows])], dm["embedding_multiplier"])
           for i in range(0, len(tokens), rows)]
     del embed
-    step = jax.jit(partial(block, dm=dm, prec=prec))
+    step = jax.jit(lambda x, w: block(x, w, dm, prec)[0])
     for layer in range(dm["layers"]):
         w = nest(make_layer(layer))
         xs = [step(x, w) for x in xs]

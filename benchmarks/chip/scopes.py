@@ -26,9 +26,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import trace_reduce
 
-# The program's scope names, in the order of a step.
+# The program's scope names, in the order of a step. ``router``,
+# ``dispatch``, ``experts`` and ``combine`` are the expert layer's parts
+# inside ``moe``; an op in one of them counts there and not in ``moe``.
 SCOPES = ("embed", "layer_scan", "attn_proj", "attention", "kv_write", "mlp", "moe",
-          "ssm", "head", "loss", "optimizer")
+          "router", "dispatch", "experts", "combine", "ssm", "head", "loss", "optimizer")
 UNSCOPED = "(unscoped)"
 OTHER = "(other programs)"
 TOP_OPS = 3                # instructions listed for each scope

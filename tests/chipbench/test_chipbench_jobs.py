@@ -4,19 +4,24 @@ The limits are set at the tiny size by the rule ``calibrate.py`` applies at
 the cell's size on the chip (program, control and planted faults over two
 seeds). Then a sound run on another seed passes, the control fails, and
 every fault a cell can have (state left unchanged, half of the batch left
-out, a token altered where it is produced) fails through the harness."""
+out, a token altered where it is produced) fails through the harness.
+A step that reports its expert choices (``moe_routes``) hands them to the
+reference, and a wrong choice fails by ``route_flip``."""
 
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from chipbench_paths import BENCH_DIR
 
 import bench
 import calibrate
+import weights
 from jobs import decode, train
+from reference import llama
 
 TINY = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
             num_key_value_heads=2, num_hidden_layers=2, vocab_size=256)
@@ -59,18 +64,23 @@ def readings(cell):
     return _READINGS[cell.name]
 
 
-def tiny_cell(name):
-    """The cell ``name`` at a tiny size: its configuration with small
-    widths and depth, its traffic with small shapes, and limits set from
-    readings at that size."""
+def tiny(name, label=None):
+    """The cell ``name`` at a tiny size, named ``label`` where given: its
+    configuration with small widths and depth, its traffic with small
+    shapes, no limits."""
     config, traffic_name, shapes = CELLS[name]
     config = bench.load_json(BENCH_DIR / "configs" / f"{config}.json")
     t = dict(bench.traffic.load(traffic_name), **shapes)
     shrink = (TINY_MOE if config.get("num_local_experts") else
               TINY_WIDE if t["job"] == "decode" else TINY)
-    cell = bench.Cell(name=name, config=dict(config, **shrink), traffic=t, chips=1,
-                      limits=None)
-    derived = calibrate.limits_of(readings(cell), t["job"])
+    return bench.Cell(name=label or name, config=dict(config, **shrink), traffic=t,
+                      chips=1, limits=None)
+
+
+def tiny_cell(name, label=None):
+    """``tiny(name, label)`` with limits set from readings at that size."""
+    cell = tiny(name, label)
+    derived = calibrate.limits_of(readings(cell), cell.traffic["job"])
     cell.limits = {k: v["limit"] for k, v in derived.items() if v["limit"] is not None}
     return cell
 
@@ -164,5 +174,98 @@ def test_control_is_not_correct(name):
     cell = tiny_cell(name)
     assert cell.limits
     for r in readings(cell):
-        if r["kind"] != "program":
+        if r["kind"] not in ("program",) + calibrate.INFORMATIONAL:
             assert not bench.judge(r["numbers"], cell.limits)[0], r
+
+
+# ---------------------------------------------------------------------------
+# Expert routes handed from the step to the reference
+# ---------------------------------------------------------------------------
+
+MOE = "granite-moe.train.s1k"
+
+
+class ReportedRoutes:
+    """Wraps the program's train step so that its metrics carry
+    ``moe_routes``, as a program that reports its expert choices would:
+    here the float32 reference's own choices, from its router on the
+    step's parameters and tokens, as a stand-in for the program's. With
+    ``fault`` "wrong_slot" one slot of each token names an expert it did
+    not choose; with "collapsed" every slot names the token's first
+    choice."""
+
+    def __init__(self, monkeypatch, cell):
+        from repro.train import step as step_mod
+        self.fault = None
+        real_make = step_mod.make_train_step
+        dm = llama.dims(cell.config)
+        experts = cell.config["num_local_experts"]
+        own = jax.jit(lambda params, tokens: llama.hidden(params, tokens, dm, "f32")[1])
+
+        def make(arch, cfg, mesh=None):
+            real = real_make(arch, cfg, mesh)
+
+            def step(params, opt_state, batch):
+                routes = np.asarray(own(weights.flatten(params), batch["tokens"][0]))
+                if self.fault == "wrong_slot":
+                    routes = calibrate.wrong_slot(routes, experts, 0)
+                elif self.fault == "collapsed":
+                    routes = np.repeat(routes[..., :1], routes.shape[-1], axis=-1)
+                params, opt_state, m = real(params, opt_state, batch)
+                return params, opt_state, dict(m, moe_routes=jnp.asarray(routes))
+
+            return step
+
+        monkeypatch.setattr(step_mod, "make_train_step", make)
+
+
+def routed_cell(monkeypatch):
+    """The tiny expert cell with a step that reports its routes, and
+    limits set from readings of that step, as ``calibrate.py`` sets them."""
+    reported = ReportedRoutes(monkeypatch, tiny(MOE))
+    return tiny_cell(MOE, MOE + ".routes"), reported
+
+
+def test_routes_of_the_references_own_choices_read_as_free_routing(monkeypatch):
+    plain = tiny_cell(MOE)
+    free, _ = run_job(plain)
+    ReportedRoutes(monkeypatch, plain)
+    held, _ = run_job(plain)
+    assert held.numbers.pop("route_flip") == 0
+    assert held.numbers == free.numbers
+
+
+def test_a_routing_fault_is_not_correct(monkeypatch):
+    """Also: the control that counts (the fp8 reference's own routes,
+    handed over as a program would hand them) and the planted faults of
+    the readings fail the limits of a step that reports its routes."""
+    cell, reported = routed_cell(monkeypatch)
+    assert cell.limits["route_flip"] > 0
+    kinds = {r["kind"] for r in readings(cell)}
+    assert {"control", "control_free", "route_fault", "half_batch"} <= kinds
+    for r in readings(cell):
+        if r["kind"] in ("control", "route_fault", "half_batch"):
+            assert not bench.judge(r["numbers"], cell.limits)[0], r
+    reported.fault = "wrong_slot"
+    out, correct = run_job(cell)
+    assert not correct, out.numbers
+    assert out.numbers["route_flip"] > cell.limits["route_flip"]
+
+
+def test_half_batch_with_routes_fails_as_a_reading(monkeypatch):
+    cell, _ = routed_cell(monkeypatch)
+    faulty_train_step(monkeypatch, "half_batch")
+    out, correct = run_job(cell)
+    assert not correct, out.numbers
+    assert out.numbers["route_flip"] == 1.0
+
+
+def test_routes_that_name_an_expert_twice_are_not_correct(monkeypatch):
+    """Every slot of a token on its first choice: each id is among the
+    reference's top-k, so only the rule that a token names ``top_k``
+    different experts catches it."""
+    cell, reported = routed_cell(monkeypatch)
+    reported.fault = "collapsed"
+    out, correct = run_job(cell)
+    assert not correct, out.numbers
+    assert out.numbers["route_flip"] == 1.0

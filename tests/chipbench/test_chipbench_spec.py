@@ -226,3 +226,120 @@ def test_a_new_cell_needs_only_new_files(tmp_path):
         assert list(line)[-1] == "checks"
     assert set(train_line["checks"]) == {"loss_gap", "grad_gap", "grad_diff", "change_gap"}
     assert "tiny reference trains" in p.stderr
+
+
+STAND_INS = '''
+import dataclasses, typing
+import weights
+from repro.configs import base
+from repro.models import lm
+
+
+@dataclasses.dataclass(frozen=True)
+class Granite(base.ArchConfig):
+    rms_norm_eps: float = 1e-5
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0        # 0 -> head_dim ** -0.5
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.attention_multiplier == 0.0:
+            object.__setattr__(self, "attention_multiplier", self.head_dim ** -0.5)
+
+
+@dataclasses.dataclass(frozen=True)
+class Dropless(lm.RunCfg):
+    capacity_factor: typing.Optional[float] = 1.25
+
+
+real_shapes = weights.param_shapes
+
+
+def shapes(arch, dtype):
+    out = real_shapes(arch, dtype)
+    return {p: v for p, v in out.items() if not (arch.tie_embeddings and p == "['lm_head']")}
+
+
+base.ArchConfig, lm.RunCfg, weights.param_shapes = Granite, Dropless, shapes
+'''
+
+
+def test_the_published_granite_cell_needs_only_new_files(tmp_path):
+    """The next step for the expert model: its configuration at published
+    form (tied head, eps 1e-6, the four multipliers, routing without
+    drops; 8 of 32 layers), its cell, limits and a reader of its expert
+    layer's scopes, by new files and entries only in a copy of the
+    benchmark. The harness takes them against a program that has the
+    fields (stand-ins of ``ArchConfig``, ``RunCfg`` and the parameter
+    tree); no job runs, since no program honours those keys yet."""
+    bench_copy = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(BENCH_DIR, bench_copy)
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    stage = json.loads((BENCH_DIR / "configs" /
+                        "granite-moe-3b-a800m-train-stage.json").read_text())
+    published = dict(stage["published"])
+    config = {k: v for k, v in stage.items() if k not in ("capacity_factor", "assumed")}
+    config.update({k: v for k, v in published.items() if k != "num_hidden_layers"},
+                  name="granite-3.0-3b-a800m-stage", published={"num_hidden_layers": 32},
+                  why_changed={"num_hidden_layers": "one stage of four"})
+    files = {
+        f"configs/{config['name']}.json": config,
+        "limits/granite-moe.train.s1k.json": {"loss_gap": 0.01, "grad_gap": 0.01,
+                                              "grad_diff": 0.1, "change_gap": 0.1,
+                                              "route_flip": 0.05},
+    }
+    for path, content in files.items():
+        (bench_copy / path).write_text(json.dumps(content))
+    (bench_copy / "metrics" / "moe_ms.train.py").write_text(
+        "import scopes\n\n\ndef read(r):\n    return scopes.scope_ms(r, 'moe', 'router', "
+        "'dispatch', 'experts', 'combine')\n")
+    cell = "granite-moe.train.s1k"
+    spec["configs"].append({"name": config["name"], "source": config["source"],
+                            "file": f"benchmarks/chip/configs/{config['name']}.json",
+                            "reduced": ["num_hidden_layers"], "why": "a test"})
+    spec["workloads"].append({"name": cell, "config": config["name"],
+                              "traffic": "train.s1k.b8", "chips": 1, "why": "a test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "yi-6b.train.s4k" in m.get("workloads", []):
+            m["workloads"].append(cell)
+    spec["per_layer"].append({"name": "moe_ms.train", "unit": "ms", "better": "lower",
+                              "source": "device_trace", "layer": "expert layer",
+                              "moves": "train_tokens_per_s", "workloads": [cell]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    assert sorted(config["published"]) == spec["configs"][-1]["reduced"]
+    assert not any(WIDTHS.search(k) for k in spec["configs"][-1]["reduced"])
+    script = textwrap.dedent(f"""
+        import json, sys
+        sys.path[:0] = [{str(bench_copy)!r}, {str(ROOT / 'src')!r}]
+        import bench, run
+        """) + STAND_INS + textwrap.dedent(f"""
+        spec = json.load(open({str(tmp_path / 'BENCHMARK.json')!r}))
+        cell = bench.load_cell({cell!r}, spec, root=bench.ROOT)
+        arch = bench.arch_of(cell.config)
+        dm = bench.reference_of(cell.config).dims(cell.config)
+        print(json.dumps({{
+            "root": str(bench.ROOT), "traffic": cell.traffic["seq_len"],
+            "limits": cell.limits, "tied": arch.tie_embeddings,
+            "arch": [arch.num_layers, arch.rms_norm_eps, arch.embedding_multiplier,
+                     arch.attention_multiplier, arch.residual_multiplier,
+                     arch.logits_scaling, arch.n_experts, arch.top_k],
+            "capacity": bench.run_cfg(cell.config).capacity_factor,
+            "reference": [dm["eps"], dm["embedding_multiplier"], dm["attention_multiplier"],
+                          dm["residual_multiplier"], dm["logits_scaling"], dm["tied"],
+                          dm["capacity_factor"]],
+            "metrics": [m["name"] for m in run.cell_metrics(spec, {cell!r}, True)],
+        }}))
+    """)
+    p = subprocess.run([sys.executable, "-c", script], cwd=tmp_path, env=harness_env(),
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
+    assert got["root"] == str(tmp_path) and got["traffic"] == 1024
+    assert got["limits"]["route_flip"] == 0.05
+    assert got["tied"] is True
+    assert got["arch"] == [8, 1e-6, 12.0, 0.015625, 0.22, 6.0, 40, 8]
+    assert got["capacity"] is None
+    assert got["reference"] == [1e-6, 12.0, 0.015625, 0.22, 6.0, True, None]
+    assert "moe_ms.train" in got["metrics"]
