@@ -3,10 +3,13 @@
 Design notes:
 
 * Attention is GQA-grouped (no KV repeat — grouped einsum keeps HLO bytes
-  honest) with an optional query-chunk scan: memory O(S * q_chunk)
-  instead of O(S^2), the XLA-level flash-attention pattern that keeps
-  32k-token prefill compilable and is also the faithful cost model for
-  the roofline. Sliding-window attention slices the KV span per chunk, so
+  honest). On TPU, causal full-span attention whose shape the kernel
+  serves (``kernels.splash.fits``) on one device runs as a flash kernel
+  with its own backward (``kernels.splash``): the fused, block-skipping
+  form of the computation. Every other case, and every other platform,
+  takes the jnp path with an optional query-chunk scan: memory
+  O(S * q_chunk) instead of O(S^2), which keeps 32k-token prefill
+  compilable. Sliding-window attention slices the KV span per chunk, so
   window archs (hymba) get the sub-quadratic compute they promise.
 * MoE uses sort-free scatter dispatch with static capacity (GShard-style):
   deterministic shapes, expert-parallel shardable, dropped-token fraction
@@ -25,6 +28,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..kernels import splash
 
 __all__ = [
     "rmsnorm",
@@ -95,65 +100,80 @@ def attention(
     causal: bool = True,
     window: int = 0,
     q_chunk: int = 0,
+    mesh: Any = None,
 ) -> jax.Array:
     """Training/prefill attention.
 
     q: [B,S,nh,hd]; k,v: [B,S,nkv,hd]. Returns [B,S,nh,hd].
+    Lowered for TPU with no ``mesh``, causal full-span attention of a
+    shape ``splash.fits`` runs the splash flash kernel. Otherwise
     ``q_chunk > 0`` scans over query chunks (O(S * chunk) memory);
     ``window > 0`` additionally slices KV to the live span per chunk.
     """
     with jax.named_scope("attention"):
-        B, S, nh, hd = q.shape
-        nkv = k.shape[2]
-        g = nh // nkv
-        qg = q.reshape(B, S, nkv, g, hd)
+        jnp_path = partial(_attention_jnp, causal=causal, window=window,
+                           q_chunk=q_chunk)
+        S, hd = q.shape[1], q.shape[3]
+        if mesh is None and causal and not window and splash.fits(S, hd):
+            return lax.platform_dependent(q, k, v, tpu=splash.causal_gqa,
+                                          default=jnp_path)
+        return jnp_path(q, k, v)
 
-        def mask_for(q_pos: jax.Array, k_pos: jax.Array) -> jax.Array:
-            m = jnp.ones((q_pos.shape[0], k_pos.shape[0]), dtype=bool)
-            if causal:
-                m &= q_pos[:, None] >= k_pos[None, :]
-            if window:
-                m &= k_pos[None, :] > q_pos[:, None] - window
-            return m
 
-        if not q_chunk or S <= q_chunk:
-            pos = jnp.arange(S)
-            out = _attend(qg, k, v, mask_for(pos, pos))
-            return out.reshape(B, S, nh, hd)
+def _attention_jnp(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                   causal: bool, window: int, q_chunk: int) -> jax.Array:
+    """``attention`` off the kernel: grouped einsums over fp32 scores."""
+    B, S, nh, hd = q.shape
+    nkv = k.shape[2]
+    g = nh // nkv
+    qg = q.reshape(B, S, nkv, g, hd)
 
-        assert S % q_chunk == 0, (S, q_chunk)
-        n_chunks = S // q_chunk
-        qc = qg.reshape(B, n_chunks, q_chunk, nkv, g, hd)
-
+    def mask_for(q_pos: jax.Array, k_pos: jax.Array) -> jax.Array:
+        m = jnp.ones((q_pos.shape[0], k_pos.shape[0]), dtype=bool)
+        if causal:
+            m &= q_pos[:, None] >= k_pos[None, :]
         if window:
-            span = min(S, window + q_chunk)  # static KV slice per chunk
+            m &= k_pos[None, :] > q_pos[:, None] - window
+        return m
 
-            def chunk_fn(_, inputs):
-                idx, qi = inputs
-                q0 = idx * q_chunk
-                k0 = jnp.maximum(q0 + q_chunk - span, 0)
-                ks = lax.dynamic_slice_in_dim(k, k0, span, axis=1)
-                vs = lax.dynamic_slice_in_dim(v, k0, span, axis=1)
-                # dynamic positions -> build mask from absolute indices
-                q_pos = q0 + jnp.arange(q_chunk)
-                k_pos = k0 + jnp.arange(span)
-                m = q_pos[:, None] >= k_pos[None, :]
-                m &= k_pos[None, :] > q_pos[:, None] - window
-                return None, _attend(qi, ks, vs, m)
-        else:
-            def chunk_fn(_, inputs):
-                idx, qi = inputs
-                q0 = idx * q_chunk
-                q_pos = q0 + jnp.arange(q_chunk)
-                k_pos = jnp.arange(S)
-                m = q_pos[:, None] >= k_pos[None, :] if causal else \
-                    jnp.ones((q_chunk, S), dtype=bool)
-                return None, _attend(qi, k, v, m)
+    if not q_chunk or S <= q_chunk:
+        pos = jnp.arange(S)
+        out = _attend(qg, k, v, mask_for(pos, pos))
+        return out.reshape(B, S, nh, hd)
 
-        idxs = jnp.arange(n_chunks)
-        _, out = lax.scan(chunk_fn, None, (idxs, jnp.moveaxis(qc, 1, 0)))
-        out = jnp.moveaxis(out, 0, 1).reshape(B, S, nh, hd)
-        return out
+    assert S % q_chunk == 0, (S, q_chunk)
+    n_chunks = S // q_chunk
+    qc = qg.reshape(B, n_chunks, q_chunk, nkv, g, hd)
+
+    if window:
+        span = min(S, window + q_chunk)  # static KV slice per chunk
+
+        def chunk_fn(_, inputs):
+            idx, qi = inputs
+            q0 = idx * q_chunk
+            k0 = jnp.maximum(q0 + q_chunk - span, 0)
+            ks = lax.dynamic_slice_in_dim(k, k0, span, axis=1)
+            vs = lax.dynamic_slice_in_dim(v, k0, span, axis=1)
+            # dynamic positions -> build mask from absolute indices
+            q_pos = q0 + jnp.arange(q_chunk)
+            k_pos = k0 + jnp.arange(span)
+            m = q_pos[:, None] >= k_pos[None, :]
+            m &= k_pos[None, :] > q_pos[:, None] - window
+            return None, _attend(qi, ks, vs, m)
+    else:
+        def chunk_fn(_, inputs):
+            idx, qi = inputs
+            q0 = idx * q_chunk
+            q_pos = q0 + jnp.arange(q_chunk)
+            k_pos = jnp.arange(S)
+            m = q_pos[:, None] >= k_pos[None, :] if causal else \
+                jnp.ones((q_chunk, S), dtype=bool)
+            return None, _attend(qi, k, v, m)
+
+    idxs = jnp.arange(n_chunks)
+    _, out = lax.scan(chunk_fn, None, (idxs, jnp.moveaxis(qc, 1, 0)))
+    out = jnp.moveaxis(out, 0, 1).reshape(B, S, nh, hd)
+    return out
 
 
 def decode_attention(

@@ -182,7 +182,8 @@ def _run_attn(arch: ArchConfig, p, h: jax.Array, positions: jax.Array, cfg: RunC
         k = _cst(h @ p["wk"], cfg, (cfg.batch_axes, None, "model")).reshape(B, S, nkv, hd)
         v = _cst(h @ p["wv"], cfg, (cfg.batch_axes, None, "model")).reshape(B, S, nkv, hd)
         q, k = rope(q, positions), rope(k, positions)
-        o = attention(q, k, v, causal=arch.causal, window=arch.window, q_chunk=cfg.q_chunk)
+        o = attention(q, k, v, causal=arch.causal, window=arch.window, q_chunk=cfg.q_chunk,
+                      mesh=cfg.mesh)
         return o.reshape(B, S, nh * hd) @ p["wo"]
 
 

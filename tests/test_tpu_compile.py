@@ -23,8 +23,13 @@ from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.models.layers import attention
 from repro.models.lm import RunCfg, init_cache, init_params
 from repro.serving.serve import make_serve_step
+from repro.train.step import TrainCfg, init_train_state, make_train_step
+
+from chipbench import chipbench_paths  # noqa: F401  (puts the chip benchmark on sys.path)
+import scopes
 
 
 @pytest.fixture(scope="module")
@@ -68,6 +73,48 @@ def test_flash_attention_compiles_for_v5e(shape, nh, nkv, hd, window):
     _compile(partial(flash_attention, causal=True, window=window),
              shape((1, nh, S, hd)), shape((1, nkv, S, hd)),
              shape((1, nkv, S, hd)))
+
+
+def _kernel_calls(compiled) -> int:
+    """The step's ``tpu_custom_call``s, after checking that the chip
+    benchmark's scope reader files each under ``attention``."""
+    text = compiled.as_text()
+    calls = [line.split(" = ", 1)[0].split()[-1].lstrip("%") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    found = scopes.op_scopes(text)
+    assert all(found[name] == "attention" for name in calls), calls
+    return len(calls)
+
+
+@pytest.mark.parametrize("nh,nkv,hd,window,calls", [
+    (32, 4, 128, 0, 3),     # yi-6b: the splash forward, dq and dkv kernels
+    (25, 5, 64, 1024, 0),   # hymba-1.5b: window and head dim 64 keep the jnp path
+], ids=["yi-6b", "hymba-1.5b"])
+def test_attention_grad_runs_the_splash_kernels_on_v5e(shape, nh, nkv, hd, window, calls):
+    """The kernel's engagement, fixed by shape: ``jax.grad`` of
+    ``layers.attention`` at 4096 tokens."""
+    S = 4096
+
+    def loss(q, k, v):
+        out = attention(q, k, v, causal=True, window=window, q_chunk=1024)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape((1, S, nh, hd)), shape((1, S, nkv, hd)), shape((1, S, nkv, hd))).compile()
+    assert _kernel_calls(compiled) == calls
+
+
+def test_yi_train_stage_step_runs_the_splash_kernels_on_v5e(shape):
+    """The step ``yi-6b.train.s4k`` runs: one yi-6b layer with embedding
+    and head, float32 weights and Adam, 1 x 4096 tokens."""
+    arch = dataclasses.replace(get_config("yi-6b"), num_layers=1)
+    cfg = TrainCfg()
+    abstract = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params, opt_state = abstract(jax.eval_shape(
+        lambda: init_train_state(arch, cfg, jax.random.PRNGKey(0))))
+    batch = {name: shape((1, 1, 4096), jnp.int32) for name in ("tokens", "labels")}
+    compiled = make_train_step(arch, cfg).lower(params, opt_state, batch).compile()
+    assert _kernel_calls(compiled) == 3
 
 
 @pytest.mark.parametrize("H", [4096, 1600], ids=["yi-6b", "hymba-1.5b"])
